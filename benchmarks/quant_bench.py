@@ -56,15 +56,14 @@ def _serve_tok_s(engine, prompts, budget: int, num_slots: int,
 
 def run(fast: bool = True) -> None:
     from repro.models import model as M
-    from repro.quant import QUANT_MODES, fp8_supported, quant_summary
+    from repro.quant import quant_summary
     from repro.serving.engine import ServeEngine
 
     cfg = _bench_cfg(fast)
     key = jax.random.PRNGKey(0)
     params = M.init_params(key, cfg)
 
-    modes = ["int8"] + (["fp8"] if fp8_supported() else [])
-    assert all(m in QUANT_MODES for m in modes)
+    modes = ["int8", "fp8"]
 
     n_req, plen, budget = (8, 16, 8) if fast else (32, 64, 32)
     rs = np.random.RandomState(0)

@@ -65,6 +65,9 @@ def main() -> None:
 
     import jax
 
+    from repro.common.runtime import init_compile_cache
+
+    init_compile_cache()
     from benchmarks import (common, fig5_patterns, kernel_bench, obs_bench,
                             optim_bench, paged_bench, quant_bench, roofline,
                             sparse_bench, spec_bench, swap_churn,

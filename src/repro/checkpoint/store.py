@@ -1,7 +1,7 @@
 """Checkpoint serialization: nested dict of arrays <-> one msgpack file.
 
 Self-contained (no orbax offline): dtype-faithful (bfloat16 via ml_dtypes
-raw bytes), atomic (tmp + os.replace), with optional zstd compression.
+raw bytes), atomic (tmp + os.replace), zstd-compressed by default.
 Restore returns host numpy arrays, so a checkpoint written under one mesh
 can be re-placed under any other - this is the elasticity primitive.
 """
@@ -12,16 +12,11 @@ from typing import Dict, Optional
 
 import zlib
 
-import msgpack
-import numpy as np
-
-try:  # optional: better ratio/speed than zlib, but not always installed
-    import zstandard
-except ImportError:
-    zstandard = None
-
 import jax
 import ml_dtypes  # ships with jax
+import msgpack
+import numpy as np
+import zstandard
 
 from repro.quant.qtensor import QTensor
 from repro.sparse.prune import PackedRows
@@ -102,15 +97,12 @@ def save_tree(path: str, tree, *, compress: bool = True,
         },
     }
     raw = msgpack.packb(payload, use_bin_type=True)
-    if compress and zstandard is not None:
+    if compress:
         # write_checksum: zstd only validates frames that carry one, and
         # the integrity check is what lets load_tree reject bit flips
-        # instead of deserializing corrupted numbers (zlib's adler32 is
-        # always on)
+        # instead of deserializing corrupted numbers
         raw = b"ZSTD" + zstandard.ZstdCompressor(
             level=3, write_checksum=True).compress(raw)
-    elif compress:
-        raw = b"ZLIB" + zlib.compress(raw, level=3)
     tmp = path + ".tmp"
     with open(tmp, "wb") as f:
         f.write(raw)
@@ -129,12 +121,8 @@ def load_tree(path: str):
         raw = f.read()
     try:
         if raw[:4] == b"ZSTD":
-            if zstandard is None:
-                raise ImportError(
-                    f"{path} is zstd-compressed but `zstandard` is not "
-                    "installed")
             raw = zstandard.ZstdDecompressor().decompress(raw[4:])
-        elif raw[:4] == b"ZLIB":
+        elif raw[:4] == b"ZLIB":  # written by older versions
             raw = zlib.decompress(raw[4:])
         payload = msgpack.unpackb(raw, raw=False)
         if not isinstance(payload, dict) or "arrays" not in payload \
@@ -144,8 +132,6 @@ def load_tree(path: str):
         for k, spec in payload["arrays"].items():
             arr = np.frombuffer(spec["data"], dtype=_np_dtype(spec["dtype"]))
             flat[k] = arr.reshape(spec["shape"])
-    except ImportError:
-        raise
     except Exception as e:
         raise ValueError(f"corrupt checkpoint {path}: {e!r}") from e
     return _unflatten(flat), payload["meta"]

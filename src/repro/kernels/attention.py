@@ -75,7 +75,7 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
                         window: Optional[int] = None,
                         scale: Optional[float] = None, cap: float = 0.0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """q: (B, H, Sq, D); k,v: (B, KH, Skv, D) with H = KH*G. Forward only."""
     B, H, Sq, D = q.shape
     KH, Skv = k.shape[1], k.shape[2]
@@ -117,7 +117,8 @@ def flash_attention_tpu(q, k, v, *, causal: bool = True,
 
 def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                   scale: float, cap: float, window: Optional[int],
-                  page: int, nbt: int, ring: int, sq: int, quant: bool):
+                  page: int, nbt: int, ring: int, sq: int, group: int,
+                  quant: bool):
     """Sq decode tokens per sequence; grid (B, H, nbt), kv-block innermost.
 
     The block table never reaches the kernel body's data path: it is a
@@ -137,6 +138,7 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(2)
+    kh = pl.program_id(1) // group
 
     @pl.when(j == 0)
     def _init():
@@ -145,11 +147,17 @@ def _paged_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
     q = q_ref[0, 0].astype(jnp.float32)  # (sq, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)  # (page, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)
+    k = k_ref[0].astype(jnp.float32)  # (page, D): kv head kh's columns
+    v = v_ref[0].astype(jnp.float32)
     if quant:
-        k = k * ks_ref[0, :, 0].astype(jnp.float32)  # (page, 1) scales
-        v = v * vs_ref[0, :, 0].astype(jnp.float32)
+        # scale pages arrive whole (page, KH); pick kv head kh's column
+        # with a one-hot reduction - no dynamic lane slice
+        onehot = jax.lax.broadcasted_iota(
+            jnp.int32, ks_ref.shape[1:], 1) == kh
+        k = k * jnp.sum(jnp.where(onehot, ks_ref[0].astype(jnp.float32),
+                                  0.0), axis=1, keepdims=True)
+        v = v * jnp.sum(jnp.where(onehot, vs_ref[0].astype(jnp.float32),
+                                  0.0), axis=1, keepdims=True)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -195,7 +203,7 @@ def paged_attention_tpu(q, k_pool, v_pool, tables, kv_lens, *,
                         window: Optional[int] = None,
                         scale: Optional[float] = None, cap: float = 0.0,
                         k_scales=None, v_scales=None,
-                        interpret: bool = True):
+                        interpret: bool):
     """Paged decode attention. q: (B, H, D) - one token per sequence - or
     (B, H, Sq, D) for a speculative multi-token verify (right-aligned
     queries, per-query causal masks); k_pool/v_pool: (num_blocks, page,
@@ -203,12 +211,18 @@ def paged_attention_tpu(q, k_pool, v_pool, tables, kv_lens, *,
     KH, 1) are given); tables: (B, nbt) int32 physical block ids;
     kv_lens: (B,) int32 valid length through the last query (linear) or
     the last query's write position (windowed). Forward only - the
-    decode path never differentiates."""
+    decode path never differentiates.
+
+    The TPU block-shape rule wants the last two dims of every block to be
+    (8, 128)-aligned or whole, so the pools are viewed as (num_blocks,
+    page, KH*D) and kv head h // G is the D-wide column block of a
+    (1, page, D) block; the scale pools are viewed as (num_blocks, page,
+    KH) and fetched a whole page at a time."""
     squeeze = q.ndim == 3
     if squeeze:
         q = q[:, :, None]  # one query: (B, H, 1, D)
     B, H, sq, D = q.shape
-    KH, page = k_pool.shape[2], k_pool.shape[1]
+    N, page, KH = k_pool.shape[:3]
     nbt = tables.shape[1]
     G = H // KH
     scale = scale if scale is not None else D**-0.5
@@ -218,21 +232,21 @@ def paged_attention_tpu(q, k_pool, v_pool, tables, kv_lens, *,
 
     kern = functools.partial(
         _paged_kernel, scale=float(scale), cap=float(cap), window=window,
-        page=page, nbt=nbt, ring=ring, sq=sq, quant=quant)
+        page=page, nbt=nbt, ring=ring, sq=sq, group=G, quant=quant)
 
     kv_spec = pl.BlockSpec(
-        (1, page, 1, D), lambda b, h, j, tbl, kl: (tbl[b, j], 0, h // G, 0))
+        (1, page, D), lambda b, h, j, tbl, kl: (tbl[b, j], 0, h // G))
     sc_spec = pl.BlockSpec(
-        (1, page, 1, 1), lambda b, h, j, tbl, kl: (tbl[b, j], 0, h // G, 0))
+        (1, page, KH), lambda b, h, j, tbl, kl: (tbl[b, j], 0, 0))
     in_specs = [
         pl.BlockSpec((1, 1, sq, D), lambda b, h, j, tbl, kl: (b, h, 0, 0)),
         kv_spec, kv_spec,
     ]
-    args = [tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-            q, k_pool, v_pool]
+    args = [tables.astype(jnp.int32), kv_lens.astype(jnp.int32), q,
+            k_pool.reshape(N, page, KH * D), v_pool.reshape(N, page, KH * D)]
     if quant:
         in_specs += [sc_spec, sc_spec]
-        args += [k_scales, v_scales]
+        args += [k_scales.reshape(N, page, KH), v_scales.reshape(N, page, KH)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,

@@ -111,7 +111,7 @@ def _affine_bwd_call(g2d, x2d, w, *, interpret: bool):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def hadamard_affine(x, w, b, interpret: bool = True):
+def hadamard_affine(x, w, b, interpret: bool):
     """y = x * w + b over the trailing dim. x: (..., d); w,b: (d,)."""
     shape = x.shape
     y = _affine_call(x.reshape(-1, shape[-1]), w, b, interpret=interpret)
@@ -254,7 +254,7 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 def fused_adapter_residual_norm(x, res, w, b, scale, *, eps: float = 1e-6,
                                 bias: Optional[jax.Array] = None,
-                                interpret: bool = True):
+                                interpret: bool):
     """Returns (x_new, h). x/res: (..., d); w/b/scale[/bias]: (d,).
 
     Differentiable: the VJP composes the Pallas affine-backward kernel

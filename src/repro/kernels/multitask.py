@@ -4,11 +4,12 @@ Each request in the batch carries a task id; its tokens must be transformed
 by that task's (w, b). The kernel uses scalar prefetch so the task-id array
 drives the BlockSpec index maps: the adapter row for request i is fetched
 from the bank directly into VMEM - no gather materialization of (B, d)
-adapter tensors in HBM.
+adapter tensors in HBM. The banks are viewed as (T, 1, d) so each
+request's row is a (1, 1, d) block: the blocked task axis is a leading
+dim, and the trailing (1, d) block covers the whole trailing array dims,
+as the TPU block-shape rule requires.
 """
 from __future__ import annotations
-
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,29 +19,22 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(tids_ref, x_ref, w_ref, b_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)  # (S, d)
-    w = w_ref[0].astype(jnp.float32)  # (d,)
+    w = w_ref[0].astype(jnp.float32)  # (1, d)
     b = b_ref[0].astype(jnp.float32)
-    o_ref[0] = (x * w[None, :] + b[None, :]).astype(o_ref.dtype)
+    o_ref[0] = (x * w + b).astype(o_ref.dtype)
 
 
-def multitask_hadamard_tpu(x, w_bank, b_bank, task_ids, *,
-                           interpret: Optional[bool] = None):
-    """x: (B,S,d); banks: (T,d); task_ids: (B,) int32.
-
-    interpret=None (default) detects the backend: compiled on TPU,
-    interpreter elsewhere. Pass an explicit bool to override (tests force
-    True; a TPU run that wants the interpreter for debugging may too).
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def multitask_hadamard_tpu(x, w_bank, b_bank, task_ids, *, interpret: bool):
+    """x: (B,S,d); banks: (T,d); task_ids: (B,) int32."""
     B, S, d = x.shape
+    T = w_bank.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, S, d), lambda i, tids: (i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i, tids: (tids[i], 0)),
-            pl.BlockSpec((1, d), lambda i, tids: (tids[i], 0)),
+            pl.BlockSpec((1, 1, d), lambda i, tids: (tids[i], 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, tids: (tids[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, S, d), lambda i, tids: (i, 0, 0)),
     )
@@ -49,4 +43,5 @@ def multitask_hadamard_tpu(x, w_bank, b_bank, task_ids, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, d), x.dtype),
         interpret=interpret,
-    )(task_ids.astype(jnp.int32), x, w_bank, b_bank)
+    )(task_ids.astype(jnp.int32), x, w_bank.reshape(T, 1, d),
+      b_bank.reshape(T, 1, d))

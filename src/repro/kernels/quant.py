@@ -22,7 +22,6 @@ transposed matmul scale-free too. Weight cotangents are symbolic zeros.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,14 +65,8 @@ def dequant_matmul_call(x2d, values, scales, *, interpret: bool,
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def dequant_matmul_tpu(x, values, scales, interpret: Optional[bool] = None):
-    """Fused dequant-matmul. x: (M, K); values: (K, N); scales: (1, N)|(N,).
-
-    interpret=None detects the backend (compiled on TPU, interpreter
-    elsewhere), matching the other kernels' auto-detection contract.
-    """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+def dequant_matmul_tpu(x, values, scales, interpret: bool):
+    """Fused dequant-matmul. x: (M, K); values: (K, N); scales: (1, N)|(N,)."""
     return dequant_matmul_call(x, values, scales, interpret=interpret)
 
 

@@ -27,24 +27,34 @@ def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, s_scr, *, L: int):
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    u = u_ref[0].astype(jnp.float32)  # (n,)
+    u = u_ref[0].astype(jnp.float32)  # (1, n)
+    n = u.shape[-1]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+
+    def col(row):
+        # (1, n) -> (n, 1) by a masked lane reduction: Mosaic has no
+        # vector-matrix dot and no cheap transpose at n = 64
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
     def step(i, _):
-        r = r_ref[0, i].astype(jnp.float32)  # (n,)
-        k = k_ref[0, i].astype(jnp.float32)
-        v = v_ref[0, i].astype(jnp.float32)
-        w = w_ref[0, i].astype(jnp.float32)
+        t_ = pl.ds(i, 1)
+        r = r_ref[0, t_].astype(jnp.float32)  # (1, n)
+        k = k_ref[0, t_].astype(jnp.float32)
+        v = v_ref[0, t_].astype(jnp.float32)
+        w = w_ref[0, t_].astype(jnp.float32)
         S = s_scr[...]
         # o_j = sum_i r_i S_ij + (sum_i r_i u_i k_i) v_j
-        o = r @ S + jnp.sum(r * u * k) * v
-        s_scr[...] = w[:, None] * S + k[:, None] * v[None, :]
-        o_ref[0, i] = o.astype(o_ref.dtype)
+        o = (jnp.sum(col(r) * S, axis=0, keepdims=True)
+             + jnp.sum(r * u * k) * v)
+        s_scr[...] = col(w) * S + col(k) * v
+        o_ref[0, t_] = o
         return 0
 
     jax.lax.fori_loop(0, L, step, 0)
 
 
-def wkv6_tpu(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def wkv6_tpu(r, k, v, w, u, *, chunk: int = 64, interpret: bool):
     """r,k,v,w: (B,H,T,n); u: (H,n). Returns o: (B,H,T,n). Zero init state."""
     B, H, T, n = r.shape
     BH = B * H
@@ -52,9 +62,13 @@ def wkv6_tpu(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
     nt = (T + L - 1) // L
 
     def flat(x):
-        return x.reshape(BH, T, n)
+        # fp32 in HBM (and out): the recurrence reads and writes one
+        # timestep row at a dynamic offset, which Mosaic does only on an
+        # unpacked (32-bit) tile
+        return x.reshape(BH, T, n).astype(jnp.float32)
 
-    u_flat = jnp.broadcast_to(u[None], (B, H, n)).reshape(BH, n)
+    # (BH, 1, n): the per-head block (1, 1, n) blocks only the leading axis
+    u_flat = jnp.broadcast_to(u[None], (B, H, n)).reshape(BH, 1, n)
 
     kern = functools.partial(_kernel, L=L)
     o = pl.pallas_call(
@@ -65,11 +79,11 @@ def wkv6_tpu(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
             pl.BlockSpec((1, L, n), lambda bh, t: (bh, t, 0)),
             pl.BlockSpec((1, L, n), lambda bh, t: (bh, t, 0)),
             pl.BlockSpec((1, L, n), lambda bh, t: (bh, t, 0)),
-            pl.BlockSpec((1, n), lambda bh, t: (bh, 0)),
+            pl.BlockSpec((1, 1, n), lambda bh, t: (bh, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, L, n), lambda bh, t: (bh, t, 0)),
-        out_shape=jax.ShapeDtypeStruct((BH, T, n), r.dtype),
+        out_shape=jax.ShapeDtypeStruct((BH, T, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
     )(flat(r), flat(k), flat(v), flat(w), u_flat)
-    return o.reshape(B, H, T, n)
+    return o.reshape(B, H, T, n).astype(r.dtype)

@@ -9,8 +9,10 @@ of pruned tenants pass through as the identity inside the fused op,
     y_i = x_i + g[t_i] * (x_i * (w[t_i] - 1) + b[t_i])
 
 so a mixed sparse/dense batch shares one kernel launch with no branch and
-no gather materialization. The gate lives as a (T, 1) fp32 column so its
-per-request block ((1, 1)) prefetches like the adapter rows do.
+no gather materialization. The banks are viewed as (T, 1, d) and the
+gate as a (T, 1, 1) fp32 column, so each request's blocks ((1, 1, d) and
+(1, 1, 1)) block only the leading task axis and prefetch like the dense
+kernel's adapter rows do.
 
 Like the dense multitask kernel it extends, this is the TPU-facing fused
 op (gates from `AdapterBank.gates()`, placed replicated via
@@ -29,7 +31,6 @@ cotangents): masks are structural, not trained.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -40,23 +41,23 @@ from jax.experimental.pallas import tpu as pltpu
 
 def _kernel(tids_ref, x_ref, w_ref, b_ref, g_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)  # (S, d)
-    w = w_ref[0].astype(jnp.float32)  # (d,)
+    w = w_ref[0].astype(jnp.float32)  # (1, d)
     b = b_ref[0].astype(jnp.float32)
-    g = g_ref[0, 0].astype(jnp.float32)  # scalar row gate
-    o_ref[0] = (x + g * (x * (w[None, :] - 1.0)
-                         + b[None, :])).astype(o_ref.dtype)
+    g = g_ref[0].astype(jnp.float32)  # (1, 1) row gate
+    o_ref[0] = (x + g * (x * (w - 1.0) + b)).astype(o_ref.dtype)
 
 
 def _call(x, w_bank, b_bank, gate, task_ids, interpret: bool):
     B, S, d = x.shape
+    T = w_bank.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((1, S, d), lambda i, tids: (i, 0, 0)),
-            pl.BlockSpec((1, d), lambda i, tids: (tids[i], 0)),
-            pl.BlockSpec((1, d), lambda i, tids: (tids[i], 0)),
-            pl.BlockSpec((1, 1), lambda i, tids: (tids[i], 0)),
+            pl.BlockSpec((1, 1, d), lambda i, tids: (tids[i], 0, 0)),
+            pl.BlockSpec((1, 1, d), lambda i, tids: (tids[i], 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, tids: (tids[i], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, S, d), lambda i, tids: (i, 0, 0)),
     )
@@ -65,31 +66,24 @@ def _call(x, w_bank, b_bank, gate, task_ids, interpret: bool):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, S, d), x.dtype),
         interpret=interpret,
-    )(task_ids.astype(jnp.int32), x, w_bank, b_bank,
-      gate.astype(jnp.float32).reshape(-1, 1))
+    )(task_ids.astype(jnp.int32), x, w_bank.reshape(T, 1, d),
+      b_bank.reshape(T, 1, d), gate.astype(jnp.float32).reshape(T, 1, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
 def masked_multitask_hadamard_tpu(x, w_bank, b_bank, gate, task_ids,
-                                  interpret: Optional[bool] = None):
-    """x: (B,S,d); banks: (T,d); gate: (T,) float {0,1}; task_ids: (B,).
-
-    interpret=None detects the backend (compiled on TPU, interpreter
-    elsewhere), matching multitask_hadamard_tpu."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+                                  interpret: bool):
+    """x: (B,S,d); banks: (T,d); gate: (T,) float {0,1}; task_ids: (B,)."""
     return _call(x, w_bank, b_bank, gate, task_ids, interpret)
 
 
 def _fwd(x, w_bank, b_bank, gate, task_ids, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     y = _call(x, w_bank, b_bank, gate, task_ids, interpret)
-    return y, (x, w_bank, b_bank, gate, task_ids, interpret)
+    return y, (x, w_bank, b_bank, gate, task_ids)
 
 
-def _bwd(_interpret, res, dy):
-    x, w_bank, b_bank, gate, task_ids, interpret = res
+def _bwd(interpret, res, dy):
+    x, w_bank, b_bank, gate, task_ids = res
     T = w_bank.shape[0]
     # dx is the same masked affine applied to dy with b = 0
     dx = _call(dy, w_bank, jnp.zeros_like(b_bank), gate, task_ids, interpret)

@@ -21,17 +21,22 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_host_mesh(data: int = 2, model: int = 4):
-    """Small mesh over forced host devices (subprocess tests)."""
+    """(data, model) mesh over the first data*model local devices: TPU
+    chips, or CPU devices forced with XLA_FLAGS=
+    --xla_force_host_platform_device_count=N. Raises when there are too
+    few devices - a "sharded" run must never quietly run unsharded."""
     n = len(jax.devices())
     if data * model > n:
-        data, model = 1, n
+        raise ValueError(f"a {data}x{model} mesh needs {data * model} "
+                         f"devices; {n} available")
     return jax.make_mesh((data, model), ("data", "model"),
-                         axis_types=(AxisType.Auto, AxisType.Auto))
+                         axis_types=(AxisType.Auto, AxisType.Auto),
+                         devices=jax.devices()[:data * model])
 
 
 def parse_mesh(spec: str):
-    """CLI mesh spec: '' -> None; 'DxM' (e.g. '2x4') -> (data, model) host
-    mesh (pair with XLA_FLAGS=--xla_force_host_platform_device_count=N)."""
+    """CLI mesh spec: '' -> None; 'DxM' (e.g. '2x4') -> (data, model)
+    mesh from `make_host_mesh`."""
     if not spec:
         return None
     data, model = (int(v) for v in spec.lower().split("x"))

@@ -28,6 +28,7 @@ import jax
 
 from repro.checkpoint import restore_into
 from repro.checkpoint.manager import CheckpointManager
+from repro.common.runtime import init_compile_cache
 from repro.common.types import OptimCfg
 from repro.configs import PAPER
 from repro.core import peft
@@ -76,6 +77,7 @@ def main():
                     help="continue from the latest snapshot in --ckpt-dir")
     ap.add_argument("--log-every", type=int, default=25)
     args = ap.parse_args()
+    init_compile_cache()
 
     cfg = PAPER[args.arch]()
     ocfg = optim_for(args.quant_moments, lr=args.lr, steps=args.steps,

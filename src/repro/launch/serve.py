@@ -46,6 +46,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.runtime import init_compile_cache
 from repro.configs import get, get_smoke
 from repro.core import peft
 from repro.core.hadamard import extract_delta, perturb_adapters
@@ -212,6 +213,7 @@ def main():
                    help="'DATAxMODEL' (e.g. 2x4): serve the backbone "
                         "sharded over a host mesh")
     args = ap.parse_args()
+    init_compile_cache()
 
     mesh = parse_mesh(args.mesh)
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)

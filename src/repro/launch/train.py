@@ -17,6 +17,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.common.runtime import init_compile_cache
 from repro.common.types import OptimCfg, TrainCfg
 from repro.configs import PAPER, get, get_smoke
 from repro.core import peft
@@ -75,6 +76,7 @@ def main():
                          "mesh (pair with XLA_FLAGS="
                          "--xla_force_host_platform_device_count=N)")
     args = ap.parse_args()
+    init_compile_cache()
 
     mesh = parse_mesh(args.mesh)
     cfg = get_smoke(args.arch) if args.smoke else get(args.arch)

@@ -17,7 +17,6 @@ from repro.quant.qtensor import (
     QUANT_PATTERNS,
     dequantize_tree,
     fake_quantize,
-    fp8_supported,
     is_qtensor,
     qdense,
     quant_summary,
@@ -34,7 +33,6 @@ __all__ = [
     "collect_stats",
     "dequantize_tree",
     "fake_quantize",
-    "fp8_supported",
     "is_qtensor",
     "qdense",
     "quant_summary",

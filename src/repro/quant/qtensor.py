@@ -2,8 +2,7 @@
 quantization primitives shared by serve-side weight quant and the
 train-side error-feedback gradient compressor.
 
-A QTensor packs `values` (int8, or fp8-e4m3 where the jax build ships the
-dtype) together with fp32 `scales`. Per-channel quantization of a matmul
+A QTensor packs `values` (int8 or fp8-e4m3) together with fp32 `scales`. Per-channel quantization of a matmul
 weight (..., d_in, d_out) keeps one scale per *output* channel - scales
 have shape (..., 1, d_out) - so the contraction dim stays scale-free and a
 fused dequant-matmul kernel can fold the scale into the accumulator
@@ -30,29 +29,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-# int8 is always available; fp8-e4m3 only where the jax build ships it
-# (the CPU container does, via ml_dtypes - compute casts up to fp32 either
-# way, so "backend support" here means the dtype exists, not MXU fp8).
-_QMAX = {"int8": 127.0}
-if hasattr(jnp, "float8_e4m3fn"):
-    _QMAX["fp8"] = 448.0  # finite max of e4m3fn (no inf encoding)
+# storage dtype and largest finite value per mode. Compute widens to fp32
+# either way, so fp8 here is a storage format, not MXU fp8.
+_QMAX = {"int8": 127.0, "fp8": 448.0}  # e4m3fn has no inf encoding
+_DTYPE = {"int8": jnp.int8, "fp8": jnp.float8_e4m3fn}
 
 QUANT_MODES = tuple(sorted(_QMAX))
 
 
-def fp8_supported() -> bool:
-    return "fp8" in _QMAX
-
-
 def _storage_dtype(mode: str):
-    if mode == "int8":
-        return jnp.int8
-    if mode == "fp8":
-        if not fp8_supported():
-            raise ValueError("fp8-e4m3 is not available in this jax build")
-        return jnp.float8_e4m3fn
-    raise ValueError(f"unknown quantization mode {mode!r} "
-                     f"(known: {QUANT_MODES})")
+    if mode not in _DTYPE:
+        raise ValueError(f"unknown quantization mode {mode!r} "
+                         f"(known: {QUANT_MODES})")
+    return _DTYPE[mode]
 
 
 @jax.tree_util.register_pytree_with_keys_class

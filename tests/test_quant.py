@@ -21,7 +21,6 @@ from repro.quant import (
     calibrate,
     dequantize_tree,
     fake_quantize,
-    fp8_supported,
     is_qtensor,
     quant_summary,
     quantize,
@@ -89,7 +88,6 @@ def test_compress_still_unbiased_with_error_feedback():
     np.testing.assert_allclose(total / 50, np.asarray(g["a"]), atol=2e-2)
 
 
-@pytest.mark.skipif(not fp8_supported(), reason="no fp8-e4m3 in this jax")
 def test_fp8_roundtrip_relative_error():
     rs = np.random.RandomState(3)
     x = rs.randn(16, 16).astype(np.float32)

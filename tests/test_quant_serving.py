@@ -76,8 +76,6 @@ def test_quantized_engine_fold_then_quant():
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.skipif(not hasattr(jnp, "float8_e4m3fn"),
-                    reason="no fp8 in this jax build")
 def test_fp8_engine_serves():
     cfg = tiny_cfg()
     params = M.init_params(KEY, cfg)
