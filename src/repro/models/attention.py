@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.common.types import AdapterCfg, ModelCfg, Slot
 from repro.models import flash
 from repro.models.layers import apply_rope, dense_init, rms_head_norm
+from repro.obs.profile import scope
 from repro.quant.qtensor import qdense
 
 INVALID_POS = jnp.iinfo(jnp.int32).max
@@ -80,6 +81,7 @@ def _lora_delta(x, a, b, alpha: float, rank: int):
     return (x @ a.astype(x.dtype)) @ b.astype(x.dtype) * (alpha / rank)
 
 
+@scope("repro.hadamard_adapter")
 def apply_hadamard(y, ad):
     """The paper's Eq. 5: elementwise affine on the feature dim.
 
@@ -221,19 +223,20 @@ def apply_attn(
         bidx = jnp.arange(B)[:, None]
         blk = block_tables[bidx, li // page]  # (B, S) physical blocks
         off = li % page
-        if is_qtensor(pool_k):
-            # per-token-per-head scales, computed independently at each
-            # write (absmax over Dh) - matches the pool's scales layout
-            mode = "int8" if vals.dtype == jnp.int8 else "fp8"
-            qk = quantize(k, mode, axis=-1)
-            qv = quantize(v, mode, axis=-1)
-            ck = QTensor(pool_k.values.at[blk, off].set(qk.values),
-                         pool_k.scales.at[blk, off].set(qk.scales))
-            cv = QTensor(pool_v.values.at[blk, off].set(qv.values),
-                         pool_v.scales.at[blk, off].set(qv.scales))
-        else:
-            ck = pool_k.at[blk, off].set(k.astype(pool_k.dtype))
-            cv = pool_v.at[blk, off].set(v.astype(pool_v.dtype))
+        with scope("repro.kv_write"):
+            if is_qtensor(pool_k):
+                # per-token-per-head scales, computed independently at each
+                # write (absmax over Dh) - matches the pool's scales layout
+                mode = "int8" if vals.dtype == jnp.int8 else "fp8"
+                qk = quantize(k, mode, axis=-1)
+                qv = quantize(v, mode, axis=-1)
+                ck = QTensor(pool_k.values.at[blk, off].set(qk.values),
+                             pool_k.scales.at[blk, off].set(qk.scales))
+                cv = QTensor(pool_v.values.at[blk, off].set(qv.values),
+                             pool_v.scales.at[blk, off].set(qv.scales))
+            else:
+                ck = pool_k.at[blk, off].set(k.astype(pool_k.dtype))
+                cv = pool_v.at[blk, off].set(v.astype(pool_v.dtype))
         new_cache = {"k": ck, "v": cv}
         k_att = flash.paged_gather(ck, block_tables, cdt)
         v_att = flash.paged_gather(cv, block_tables, cdt)
@@ -293,14 +296,15 @@ def apply_attn(
     G = H // KH
     qg = q.reshape(B, S, KH, G, Dh)
     scale = cfg.query_scale if cfg.query_scale is not None else Dh**-0.5
-    out = flash.attend(
-        qg, k_att, v_att,
-        q_pos=q_pos, kv_pos=kv_pos, kv_len=eff_len,
-        causal=causal and not is_cross,
-        window=slot.window, scale=scale, cap=cfg.attn_softcap,
-        q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
-        tile_dtype=cfg.attn_tile_dtype,
-    )
+    with scope("repro.attn_core"):
+        out = flash.attend(
+            qg, k_att, v_att,
+            q_pos=q_pos, kv_pos=kv_pos, kv_len=eff_len,
+            causal=causal and not is_cross,
+            window=slot.window, scale=scale, cap=cfg.attn_softcap,
+            q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
+            tile_dtype=cfg.attn_tile_dtype,
+        )
     out = out.reshape(B, S, H * Dh)
 
     # --- paper Eq. 7 literal placement: adapter on Concat(heads) ---

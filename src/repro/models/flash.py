@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.costmode import scan_unroll
+from repro.obs.profile import scope
 
 NEG_INF = -1e30
 
@@ -44,6 +45,16 @@ def _pad_to(x, size: int, axis: int):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _pad_kv(x, size: int):
+    """K or V padded along the sequence to whole kv chunks. Over a decode's
+    max_len cache view this copies the whole view, so the pad is named
+    with the gather that built the view (`repro.kv_gather`); a prefill or
+    train step whose length is not a whole number of chunks shows its pad
+    under that name too."""
+    with scope("repro.kv_gather"):
+        return _pad_to(x, size, 1)
 
 
 def _mask(q_pos, kv_pos, kv_len, causal: bool, window: Optional[int]):
@@ -102,8 +113,8 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
         jnp.iinfo(jnp.int32).max
     )
     q_r = _pad_to(q, nq * qc, 1).reshape(B, nq, qc, KH, G, D).transpose(1, 0, 2, 3, 4, 5)
-    k_r = _pad_to(k, nk * kc, 1).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
-    v_r = _pad_to(v, nk * kc, 1).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
+    k_r = _pad_kv(k, nk * kc).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
+    v_r = _pad_kv(v, nk * kc).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
     # chunk-index-leading position tiles: (nq, qc) / (nq, B, qc) etc.
     qp_r = (qp.reshape(B, nq, qc).transpose(1, 0, 2) if q_pos.ndim == 2
             else qp.reshape(nq, qc))
@@ -120,8 +131,8 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
     if window is not None and causal:
         n_win = min(nk, _cdiv(window + qc - 1, kc) + 1)
     use_band = n_win < nk and not batched_pos
-    k_flat = _pad_to(k, nk * kc, 1)
-    v_flat = _pad_to(v, nk * kc, 1)
+    k_flat = _pad_kv(k, nk * kc)
+    v_flat = _pad_kv(v, nk * kc)
 
     def per_q(_, xs):
         q_i, qpos_i = xs
@@ -328,6 +339,7 @@ def attend(q, k, v, *, q_pos, kv_pos, kv_len=None, causal=True, window=None,
                            int(q_chunk), int(kv_chunk), str(tile_dtype))
 
 
+@scope("repro.kv_gather")
 def paged_gather(pool, tables, dtype):
     """Gather a per-sequence contiguous KV view out of a paged block pool.
 

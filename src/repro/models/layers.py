@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.common.types import ModelCfg
+from repro.obs.profile import scope
 from repro.quant.qtensor import qdense
 
 # ---------------------------------------------------------------------------
@@ -93,6 +94,7 @@ def mlp_init(key, cfg: ModelCfg, d_in: Optional[int] = None, d_ff: Optional[int]
     return p
 
 
+@scope("repro.mlp")
 def apply_mlp(p, cfg: ModelCfg, x, ia3=None):
     h = qdense(x, p["wi"], cfg.cdtype, tag="mlp/wi")
     if "bi" in p:

@@ -28,6 +28,7 @@ from repro.models.program import (
     group_init,
     group_pool_init,
 )
+from repro.obs.profile import scope
 from repro.quant.qtensor import qdense
 
 # ---------------------------------------------------------------------------
@@ -96,6 +97,7 @@ def embed_tokens(params, cfg: ModelCfg, tokens, positions=None, type_ids=None):
     return x
 
 
+@scope("repro.lm_head")
 def lm_logits(params, cfg: ModelCfg, h):
     if cfg.tie_embeddings:
         # the embed table stays dense (it is a gather path, not a matmul

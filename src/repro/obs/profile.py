@@ -1,29 +1,27 @@
-"""Profiling hooks: kernel-level scopes and whole-program trace capture.
+"""Profiling hooks: device scopes, host spans and trace capture.
 
-Two layers:
+Three pieces:
 
-  * `scope(name)` / `annotate(name)` - cheap annotations. `scope` is
-    `jax.named_scope`: applied at trace time inside jitted code, it names
-    the enclosed ops in HLO and in profiler timelines (the Pallas
-    hadamard / paged-attention / dequant-matmul dispatches in
-    `repro.kernels.ops` are wrapped with it, so a captured trace
-    attributes device time to the kernel that spent it). `annotate` is a
-    host-side `jax.profiler.TraceAnnotation` region for Python-level
-    phases (a scheduler tick, an admission) - a no-op unless a capture is
-    running.
-  * `profiler_trace(log_dir)` / `ProfiledTicks` - capture. The context
-    manager brackets a region with `jax.profiler.start_trace/stop_trace`
-    (TensorBoard-loadable, includes HLO + annotations). `ProfiledTicks`
-    is the `launch/serve --profile-dir` hook: start capture now, stop
-    after N scheduler ticks, tolerate the serve draining earlier.
-
-Everything here degrades to a no-op if the installed jax lacks the
-profiler surface (minimal CPU builds): serving must never fail because
-profiling could not start.
+  * `scope(name)` - `jax.named_scope`: applied at trace time inside
+    jitted code, it names the enclosed ops in the HLO's op metadata and
+    so in profiler timelines. It changes nothing else in the compiled
+    program. The model step names its parts with it (`repro.kv_write`,
+    `repro.kv_gather`, `repro.attn_core`, `repro.hadamard_adapter`,
+    `repro.mlp`, `repro.lm_head`), and the Pallas dispatches in
+    `repro.kernels.ops` name theirs.
+  * `annotate(name)` / `span(counters, name)` - host regions on the
+    profiler's clock (`jax.profiler.TraceAnnotation`, a no-op unless a
+    capture is running). A `span` also adds its seconds and one call to
+    its phase's `phase_counters` (`serve_phase_seconds_total` /
+    `serve_phase_calls_total{sched, phase}`), so the serving tick's
+    phases are counted in every run, traced or not.
+  * `ProfiledTicks` - capture: the `launch/serve --profile-dir` hook.
+    Starts `jax.profiler.start_trace` now and stops after N scheduler
+    ticks, tolerating a serve that drains earlier.
 """
 from __future__ import annotations
 
-import contextlib
+import time
 import warnings
 
 import jax
@@ -35,29 +33,54 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
-def annotate(name: str):
-    """Host-side profiler annotation region (no-op outside a capture)."""
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - profiler-less build
-        return contextlib.nullcontext()
+def annotate(name: str, **attrs):
+    """Host-side profiler region (no-op outside a capture); `attrs` come
+    back as the trace event's stats."""
+    return jax.profiler.TraceAnnotation(name, **attrs)
 
 
-@contextlib.contextmanager
-def profiler_trace(log_dir: str):
-    """Capture a JAX profiler trace of the enclosed region into
-    `log_dir` (view with TensorBoard's profile plugin or Perfetto)."""
-    started = False
-    try:
-        jax.profiler.start_trace(log_dir)
-        started = True
-    except Exception as e:  # pragma: no cover - profiler-less build
-        warnings.warn(f"profiler trace not started: {e}")
-    try:
-        yield
-    finally:
-        if started:
-            jax.profiler.stop_trace()
+def phase_counters(registry, sched: str, phase: str):
+    """The (seconds, calls) counter pair of one host phase:
+    `serve_phase_seconds_total` and `serve_phase_calls_total{sched,
+    phase}`. With a disabled registry both are its shared no-ops."""
+    return (registry.counter("serve_phase_seconds_total",
+                             sched=sched, phase=phase),
+            registry.counter("serve_phase_calls_total",
+                             sched=sched, phase=phase))
+
+
+class span:
+    """A host phase on the profiler's clock and in the registry:
+
+        emit = phase_counters(obs, "paged", "emit")  # once, at set-up
+        with span(emit, "serve.emit"):
+            ...
+
+    opens `annotate(name, **attrs)` and, on exit, adds the seconds spent
+    and one call to the phase's (seconds, calls) counters. `seconds`
+    holds the reading once the span closed; `set_metadata(**attrs)` adds
+    attrs known only after the work ran."""
+
+    __slots__ = ("_ann", "_seconds", "_calls", "_t0", "seconds")
+
+    def __init__(self, counters, name: str, **attrs):
+        self._seconds, self._calls = counters
+        self._ann = annotate(name, **attrs)
+        self.seconds = 0.0
+
+    def set_metadata(self, **attrs) -> None:
+        self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._t0
+        self._seconds.inc(self.seconds)
+        self._calls.inc()
+        self._ann.__exit__(*exc)
 
 
 class ProfiledTicks:

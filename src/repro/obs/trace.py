@@ -5,14 +5,14 @@ marks relative to the request's submit time. The scheduler marks the
 canonical lifecycle:
 
     submit -> [defer ...] -> admit -> prefill{kind=cold|full_hit|partial_hit}
-           -> first_token -> token* [verify{accepted=a}]* -> retire{reason}
+           -> first_token -> [verify{accepted=a}]* -> retire{reason, tokens}
 
 with KV-block attribution (`blocks=` on paged admissions) and bank-pin
 attribution (`row=`/`adapter=` on multi-tenant admissions) carried in the
 attrs. Tests assert lifecycle completeness under the scheduler fuzz
 oracle: every completed request's trace starts with submit, admits
-exactly once, counts one `token` mark per emitted token, and ends with
-retire.
+exactly once, and ends with retire, whose `tokens` is the completion's
+token count.
 
 Tracing is bounded (finished traces go to a `keep`-sized deque) and can
 be disabled outright - a disabled tracer hands out one shared null trace

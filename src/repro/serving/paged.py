@@ -375,21 +375,24 @@ class PagedScheduler(Scheduler):
     # -- admission ----------------------------------------------------------
 
     def _admit_one(self, slot_idx: int, rid: int, req: Request,
-                   submit_t: float):
+                   submit_t: float) -> str:
         row = req.task_id
         if req.adapter is not None:
             row = self.engine.acquire_adapter(req.adapter)  # pins the row
         try:
-            self._admit_paged(slot_idx, rid, req, submit_t, row)
+            kind = self._admit_paged(slot_idx, rid, req, submit_t, row)
         except BlockPoolFullError:
             if req.adapter is not None:
                 self.engine.release_adapter(req.adapter)
             raise
         queue_s = time.perf_counter() - submit_t
         self._m_queue_s.observe(queue_s)
+        return kind
 
     def _admit_paged(self, slot_idx: int, rid: int, req: Request,
-                     submit_t: float, row: int):
+                     submit_t: float, row: int) -> str:
+        """Prefill (or replay) the prompt into the pool; returns the hit
+        kind."""
         prompt = np.asarray(req.prompt, np.int32).reshape(-1)
         S = len(prompt)
         page = self.page
@@ -467,7 +470,8 @@ class PagedScheduler(Scheduler):
                     start=m * page, kv_len=S,
                     last_pos=S - m * page - 1,
                     task_ids=np.asarray([row]))
-                st.prefill_logits = np.asarray(logits[:, -1:])
+                with self._span("prefill_wait"):
+                    st.prefill_logits = np.asarray(logits[:, -1:])
                 hit_kind = "partial_hit"  # counted by match_prefix
             else:
                 # ---- cold: prefill the page-aligned prompt, insert ----
@@ -486,7 +490,8 @@ class PagedScheduler(Scheduler):
                     last_pos=None if (self._windowed or P == S) else S - 1)
                 self.pool = self.engine.paged_insert(
                     self.pool, fresh, tbl[:nbl])
-                st.prefill_logits = np.asarray(logits[:, -1:])
+                with self._span("prefill_wait"):
+                    st.prefill_logits = np.asarray(logits[:, -1:])
                 self._c_cold.inc()
                 hit_kind = "cold"
 
@@ -509,6 +514,7 @@ class PagedScheduler(Scheduler):
         if not self._emit(slot_idx, st, st.next_tok):
             self._tok[slot_idx] = st.next_tok
             self._pos[slot_idx] = st.pos
+        return hit_kind
 
     # -- retirement ---------------------------------------------------------
 
@@ -541,52 +547,38 @@ class PagedScheduler(Scheduler):
     _defer_errors = (BankFullError, BlockPoolFullError)
 
     def _step_impl(self) -> int:
-        t0 = time.perf_counter()
         self._do_admissions()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return 0
-
-        # allocate-on-write: hand a fresh page to every slot whose next
-        # write crosses a page boundary. The reservation invariant
-        # (free >= reserved, one unit released per allocation) makes this
-        # infallible mid-decode - admission already paid for the worst case.
-        for i in occupied:
-            st = self.slots[i]
-            p = int(self._pos[i])
-            j = p // self.page
-            if p % self.page == 0 and j < st.nb_worst and not self.tables[i, j]:
-                self.tables[i, j] = self.alloc.alloc()
-                st.nb_entries += 1
-                self._reserved -= 1
-
-        logits, self.pool = self.engine.paged_decode_step(
-            self.pool, jnp.asarray(self._tok[:, None]),
-            jnp.asarray(self._pos), self.tables, task_ids=self._task.copy())
+        with self._span("plan"):
+            # allocate-on-write: hand a fresh page to every slot whose next
+            # write crosses a page boundary. The reservation invariant
+            # (free >= reserved, one unit released per allocation) makes
+            # this infallible mid-decode - admission already paid for the
+            # worst case.
+            for i in occupied:
+                st = self.slots[i]
+                p = int(self._pos[i])
+                j = p // self.page
+                if (p % self.page == 0 and j < st.nb_worst
+                        and not self.tables[i, j]):
+                    self.tables[i, j] = self.alloc.alloc()
+                    st.nb_entries += 1
+                    self._reserved -= 1
+            tok = jnp.asarray(self._tok[:, None])
+            pos = jnp.asarray(self._pos)
+            task = self._task.copy()
+        with self._span("decode"):
+            logits, self.pool = self.engine.paged_decode_step(
+                self.pool, tok, pos, self.tables, task_ids=task)
         self._ticks += 1
-        any_greedy = any(not (self.slots[i].req.top_k
-                              and self.slots[i].rng is not None)
-                         for i in occupied)
-        greedy = (np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-                  if any_greedy else None)
+        return self._sample_and_emit(occupied, logits)
 
-        produced = 0
-        for i in occupied:
-            st = self.slots[i]
-            st.pos += 1
-            if st.req.top_k and st.rng is not None:
-                tok = self._sample_one(logits[i:i + 1], st)
-            else:
-                tok = int(greedy[i])
-            st.next_tok = tok
-            produced += 1
-            if not self._emit(i, st, tok):
-                self._tok[i] = tok
-                self._pos[i] = st.pos
+    def _post_tick(self) -> None:
         self._g_free_blocks.set(self.alloc.num_free)
         self._g_reserved_blocks.set(self._reserved)
-        self._post_tick(t0)
-        return produced
+        super()._post_tick()
 
     # -- accounting ---------------------------------------------------------
 

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.obs import MetricsRegistry
+from repro.obs import MetricsRegistry, StepWatchdog, phase_counters, span
 from repro.obs.slo import SLOMonitor, SLOSpec
 from repro.serving.admission import (AdmissionConfig, AdmissionController,
                                      AdmissionShedError)
@@ -111,9 +111,15 @@ class Scheduler:
 
     _sched_kind = "contiguous"  # `sched=` label on every metric series
     # engine fns that must never recompile once serving started (prefill is
-    # exempt: it legitimately compiles one shape per prompt-length bucket)
+    # exempt: it legitimately compiles one shape per prompt-length bucket,
+    # so its compiles are counted, not warned about)
     _RETRACE_KEYS = ("decode", "decode_paged", "verify", "verify_paged",
                      "draft")
+    # host phases of a tick, each a `serve.<phase>` span: `tick` is the
+    # whole step(); admit, plan, decode, sample_wait and emit are disjoint
+    # parts of it; prefill_wait lies inside admit
+    PHASES = ("tick", "admit", "prefill_wait", "plan", "decode",
+              "sample_wait", "emit")
 
     def __init__(self, engine, *, num_slots: int, max_len: int,
                  stream: Optional[Callable[[int, int], None]] = None,
@@ -170,6 +176,15 @@ class Scheduler:
         self._m_latency = self.obs.histogram("serve_latency_s", sched=kind)
         self._m_retrace = self.obs.counter(
             "serve_retrace_events_total", sched=kind)
+        self._m_prefill_compiles = self.obs.counter(
+            "serve_prefill_compiles_total", sched=kind)
+        # (seconds, calls) per phase: the counters each span increments
+        self._m_phase = {p: phase_counters(self.obs, kind, p)
+                         for p in self.PHASES}
+        self._m_slow_ticks = self.obs.counter("serve_slow_ticks_total",
+                                              sched=kind)
+        # admission ticks run long, hence 4x rather than the train default
+        self._tick_watch = StepWatchdog(factor=4.0)
         # admission-control instruments exist (at zero) even without an
         # attached controller, so report() keys are stable either way
         self._m_shed = self.obs.counter(
@@ -189,6 +204,7 @@ class Scheduler:
         # (engines arrive with compile history from warmup / parity runs)
         self._trace_watch: List[tuple] = []
         self._trace_allow: Dict[tuple, int] = {}
+        self._prefill_seen: Dict[str, int] = {}
         tc = getattr(self.engine, "trace_counts", None)
         if tc is not None:
             self._watch_traces("engine", tc)
@@ -204,9 +220,14 @@ class Scheduler:
         for k in self._RETRACE_KEYS:
             if k in trace_counts:
                 self._trace_allow[(src, k)] = trace_counts.get(k, 0) + 1
+        self._prefill_seen[src] = trace_counts.get("prefill", 0)
 
     def _check_retraces(self) -> None:
         for src, tc in self._trace_watch:
+            n = tc.get("prefill", 0)
+            if n > self._prefill_seen[src]:
+                self._m_prefill_compiles.inc(n - self._prefill_seen[src])
+                self._prefill_seen[src] = n
             for k in self._RETRACE_KEYS:
                 allow = self._trace_allow.get((src, k))
                 if allow is None:
@@ -222,12 +243,27 @@ class Scheduler:
                           "the steady-state serving path", file=sys.stderr)
                     self._trace_allow[(src, k)] = n
 
-    def _post_tick(self, t0: float) -> None:
-        """Per-tick bookkeeping shared by every scheduler flavour's step():
-        tick latency, tick count, and the zero-retrace invariant check."""
-        self._m_tick_s.observe(time.perf_counter() - t0)
+    def _post_tick(self) -> None:
+        """Per-tick bookkeeping shared by every scheduler flavour's decode
+        tick, at the end of its emit phase: tick count, the zero-retrace
+        invariant check and the prefill compile count."""
         self._m_ticks.inc()
         self._check_retraces()
+
+    def _span(self, phase: str, **attrs) -> span:
+        return span(self._m_phase[phase], "serve." + phase, **attrs)
+
+    def _slow_tick(self, seconds: float, phase_s: Dict[str, float],
+                   admits: int) -> None:
+        """A tick the watchdog flagged: count it and record where its time
+        went (`phase_s`, `admits`: the phase counters before the tick)."""
+        self._m_slow_ticks.inc()
+        self.obs.event(
+            "slow_tick", sched=self._sched_kind, tick=self._ticks,
+            seconds=seconds, baseline_s=self._tick_watch.stragglers[-1][2],
+            admissions=self._m_phase["admit"][1].value - admits,
+            phases={p: c.value - phase_s[p]
+                    for p, (c, _) in self._m_phase.items() if p != "tick"})
 
     def _pre_tick(self) -> None:
         """Runs exactly once per `step()` call, BEFORE admissions - even on
@@ -346,7 +382,6 @@ class Scheduler:
         if not st.tokens:
             st.first_tok_t = time.perf_counter()
             st.trace.mark("first_token")
-        st.trace.mark("token")
         self._m_tokens.inc()
         st.tokens.append(tok)
         if self.stream is not None:
@@ -393,10 +428,11 @@ class Scheduler:
         self.slots[slot_idx] = None  # immediately reusable
 
     def _admit_one(self, slot_idx: int, rid: int, req: Request,
-                   submit_t: float):
-        """Admit one request. Raises BankFullError (before any state is
-        touched) when the request names an adapter and every bank row is
-        pinned - the caller defers the whole queue to a later tick."""
+                   submit_t: float) -> str:
+        """Admit one request; returns its prefill kind. Raises
+        BankFullError (before any state is touched) when the request names
+        an adapter and every bank row is pinned - the caller defers the
+        whole queue to a later tick."""
         row = req.task_id
         if req.adapter is not None:
             row = self.engine.acquire_adapter(req.adapter)  # pins the row
@@ -424,11 +460,13 @@ class Scheduler:
         st = _Slot(request_id=rid, req=req, rng=rng, pos=S, row=row,
                    submit_t=submit_t, trace=tr)
         self.slots[slot_idx] = st
-        st.next_tok = self._sample_one(logits, st)
+        with self._span("prefill_wait"):
+            st.next_tok = self._sample_one(logits, st)
         self._task[slot_idx] = row
         if not self._emit(slot_idx, st, st.next_tok):
             self._tok[slot_idx] = st.next_tok
             self._pos[slot_idx] = st.pos
+        return "cold"
 
     # -- the tick -----------------------------------------------------------
 
@@ -454,7 +492,9 @@ class Scheduler:
             idx = free.pop()
             rid, req, submit_t = self.queue.popleft()
             try:
-                self._admit_one(idx, rid, req, submit_t)
+                with self._span("admit", request_id=rid, slot=idx) as adm:
+                    adm.set_metadata(
+                        kind=self._admit_one(idx, rid, req, submit_t))
             except KeyError:
                 # the adapter was validated at submit but unpublished (and
                 # its row evicted) before admission - runtime removal is a
@@ -491,43 +531,63 @@ class Scheduler:
         step across all occupied slots. Returns the number of tokens
         generated this tick. The body lives in `_step_impl` so flavours
         (and the spec schedulers' degraded plain-decode fallback) can
-        delegate without re-running the pre-tick hooks."""
-        self._pre_tick()
-        return self._step_impl()
+        delegate without re-running the pre-tick hooks.
+
+        The whole tick is the `serve.tick` span and feeds `serve_tick_s`;
+        ticks that ran a decode step also feed the slow-tick watchdog (an
+        idle tick's microseconds would drag its baseline down)."""
+        phase_s = {p: c.value for p, (c, _) in self._m_phase.items()}
+        admits = self._m_phase["admit"][1].value
+        with self._span("tick") as tick:
+            self._pre_tick()
+            produced = self._step_impl()
+        self._m_tick_s.observe(tick.seconds)
+        if produced and self._tick_watch.observe(self._ticks, tick.seconds):
+            self._slow_tick(tick.seconds, phase_s, admits)
+        return produced
 
     def _step_impl(self) -> int:
-        t0 = time.perf_counter()
         self._do_admissions()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return 0
-
-        logits, self.caches = self.engine.decode_step(
-            self.caches, jnp.asarray(self._tok[:, None]),
-            jnp.asarray(self._pos), task_ids=self._task.copy())
+        with self._span("plan"):
+            tok = jnp.asarray(self._tok[:, None])
+            pos = jnp.asarray(self._pos)
+            task = self._task.copy()
+        with self._span("decode"):
+            logits, self.caches = self.engine.decode_step(
+                self.caches, tok, pos, task_ids=task)
         self._ticks += 1
-        # one fused argmax covers every greedy slot; sampled slots draw
-        # from their own rng stream individually
-        any_greedy = any(not (self.slots[i].req.top_k
-                              and self.slots[i].rng is not None)
-                         for i in occupied)
-        greedy = (np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-                  if any_greedy else None)
+        return self._sample_and_emit(occupied, logits)
 
-        produced = 0
-        for i in occupied:
-            st = self.slots[i]
-            st.pos += 1
-            if st.req.top_k and st.rng is not None:
-                tok = self._sample_one(logits[i:i + 1], st)
-            else:
-                tok = int(greedy[i])
-            st.next_tok = tok
-            produced += 1
-            if not self._emit(i, st, tok):
-                self._tok[i] = tok
-                self._pos[i] = st.pos
-        self._post_tick(t0)
+    def _sample_and_emit(self, occupied: List[int], logits) -> int:
+        """The tail of a one-token decode tick: the argmax pull
+        (`serve.sample_wait`), then each slot's token, stream callback and
+        retirement, and the tick's bookkeeping (`serve.emit`)."""
+        with self._span("sample_wait"):
+            # one fused argmax covers every greedy slot; sampled slots draw
+            # from their own rng stream individually
+            any_greedy = any(not (self.slots[i].req.top_k
+                                  and self.slots[i].rng is not None)
+                             for i in occupied)
+            greedy = (np.asarray(jnp.argmax(logits[:, -1], axis=-1))
+                      if any_greedy else None)
+        with self._span("emit"):
+            produced = 0
+            for i in occupied:
+                st = self.slots[i]
+                st.pos += 1
+                if st.req.top_k and st.rng is not None:
+                    tok = self._sample_one(logits[i:i + 1], st)
+                else:
+                    tok = int(greedy[i])
+                st.next_tok = tok
+                produced += 1
+                if not self._emit(i, st, tok):
+                    self._tok[i] = tok
+                    self._pos[i] = st.pos
+            self._post_tick()
         return produced
 
     # -- batch driver -------------------------------------------------------

@@ -46,7 +46,6 @@ Restrictions:
 """
 from __future__ import annotations
 
-import time
 from typing import List, Optional, Tuple
 
 import jax
@@ -280,51 +279,55 @@ class _SpecMixin:
             prompt = np.pad(prompt, ((0, 0), (0, P - S)))
         self.draft_lane.admit(slot_idx, prompt, last_pos=S - 1)
 
-    def _spec_emit(self, occupied: List[int], toks_h: np.ndarray,
-                   logits) -> int:
-        """Per-slot acceptance against the verify logits (B, k+1, V).
+    def _spec_emit(self, occupied: List[int], toks, logits) -> int:
+        """Per-slot acceptance of the drafted `toks` (B, k+1) against the
+        verify logits (B, k+1, V), after pulling both (`serve.sample_wait`).
         Greedy slots emit their accepted prefix plus the correction token;
         sampled slots draw ONE token from position 0's distribution.
         Acceptance is capped at the EFFECTIVE depth (admission ladder);
         drafted counts the static k - that is the draft work actually
         spent, which is what the acceptance-rate objective should see."""
-        k = self.spec_k_eff
-        greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (B, k+1)
-        self._c_spec_ticks.inc()
-        produced = 0
-        for i in occupied:
-            st = self.slots[i]
-            if st.req.top_k and st.rng is not None:
-                # logits[:, 0] is bit-identical to plain decode (causal
-                # masks hide every draft write); rejected drafts are the
-                # a=0 rollback case
-                st.pos += 1
-                tok = self._sample_one(logits[i:i + 1, :1], st)
-                st.next_tok = tok
-                produced += 1
-                if not self._emit(i, st, tok):
+        with self._span("sample_wait"):
+            greedy = np.asarray(jnp.argmax(logits, axis=-1))  # (B, k+1)
+            toks_h = np.asarray(toks)
+        with self._span("emit"):
+            k = self.spec_k_eff
+            self._c_spec_ticks.inc()
+            produced = 0
+            for i in occupied:
+                st = self.slots[i]
+                if st.req.top_k and st.rng is not None:
+                    # logits[:, 0] is bit-identical to plain decode (causal
+                    # masks hide every draft write); rejected drafts are the
+                    # a=0 rollback case
+                    st.pos += 1
+                    tok = self._sample_one(logits[i:i + 1, :1], st)
+                    st.next_tok = tok
+                    produced += 1
+                    if not self._emit(i, st, tok):
+                        self._tok[i] = tok
+                        self._pos[i] = st.pos
+                    continue
+                a = 0
+                while a < k and toks_h[i, a + 1] == greedy[i, a]:
+                    a += 1
+                self._c_drafted.inc(self.spec_k)
+                self._c_accepted.inc(a)
+                st.trace.mark("verify", accepted=a, drafted=k)
+                done = False
+                tok = 0
+                for j in range(a + 1):  # a accepted drafts + the correction
+                    st.pos += 1
+                    tok = int(greedy[i, j])
+                    st.next_tok = tok
+                    produced += 1
+                    if self._emit(i, st, tok):
+                        done = True
+                        break
+                if not done:
                     self._tok[i] = tok
                     self._pos[i] = st.pos
-                continue
-            a = 0
-            while a < k and toks_h[i, a + 1] == greedy[i, a]:
-                a += 1
-            self._c_drafted.inc(self.spec_k)
-            self._c_accepted.inc(a)
-            st.trace.mark("verify", accepted=a, drafted=k)
-            done = False
-            tok = 0
-            for j in range(a + 1):  # a accepted drafts + the correction
-                st.pos += 1
-                tok = int(greedy[i, j])
-                st.next_tok = tok
-                produced += 1
-                if self._emit(i, st, tok):
-                    done = True
-                    break
-            if not done:
-                self._tok[i] = tok
-                self._pos[i] = st.pos
+            self._post_tick()
         return produced
 
     @property
@@ -365,29 +368,30 @@ class SpecScheduler(_SpecMixin, Scheduler):
         return super().submit(req)
 
     def _admit_one(self, slot_idx, rid, req, submit_t):
-        super()._admit_one(slot_idx, rid, req, submit_t)
+        kind = super()._admit_one(slot_idx, rid, req, submit_t)
         self._admit_draft(slot_idx, req)
+        return kind
 
     def _step_impl(self) -> int:
         if self.spec_k_eff == 0:
             # fully stepped down: plain one-token decode ticks (the first
             # compile of `decode` here is within the retrace allowance)
             return Scheduler._step_impl(self)
-        t0 = time.perf_counter()
         self._do_admissions()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return 0
-        tok = jnp.asarray(self._tok)
-        pos = jnp.asarray(self._pos)
-        drafts = self.draft_lane.draft(tok, pos)  # (B, k)
-        toks = jnp.concatenate([tok[:, None], drafts], axis=1)  # (B, k+1)
-        logits, self.caches = self.engine.verify_step(
-            self.caches, toks, pos, task_ids=self._task.copy())
+        with self._span("plan"):
+            tok = jnp.asarray(self._tok)
+            pos = jnp.asarray(self._pos)
+            task = self._task.copy()
+        with self._span("decode"):
+            drafts = self.draft_lane.draft(tok, pos)  # (B, k)
+            toks = jnp.concatenate([tok[:, None], drafts], axis=1)  # (B, k+1)
+            logits, self.caches = self.engine.verify_step(
+                self.caches, toks, pos, task_ids=task)
         self._ticks += 1
-        produced = self._spec_emit(occupied, np.asarray(toks), logits)
-        self._post_tick(t0)
-        return produced
+        return self._spec_emit(occupied, toks, logits)
 
 
 class SpecPagedScheduler(_SpecMixin, PagedScheduler):
@@ -431,40 +435,39 @@ class SpecPagedScheduler(_SpecMixin, PagedScheduler):
         return super().submit(req)
 
     def _admit_one(self, slot_idx, rid, req, submit_t):
-        super()._admit_one(slot_idx, rid, req, submit_t)
+        kind = super()._admit_one(slot_idx, rid, req, submit_t)
         self._admit_draft(slot_idx, req)
+        return kind
 
     def _step_impl(self) -> int:
         if self.spec_k_eff == 0:
             # fully stepped down: plain paged decode ticks
             return PagedScheduler._step_impl(self)
-        t0 = time.perf_counter()
         self._do_admissions()
         occupied = [i for i, s in enumerate(self.slots) if s is not None]
         if not occupied:
             return 0
-        # allocate-on-write, widened to the verify's whole write range
-        # pos..pos+k: every page it can touch must be real BEFORE the tick
-        # (the null block would silently swallow accepted KV)
-        for i in occupied:
-            st = self.slots[i]
-            p0 = int(self._pos[i])
-            for j in range(p0 // self.page,
-                           min((p0 + self.spec_k) // self.page,
-                               st.nb_worst - 1) + 1):
-                if not self.tables[i, j]:
-                    self.tables[i, j] = self.alloc.alloc()
-                    st.nb_entries += 1
-                    self._reserved -= 1
-        tok = jnp.asarray(self._tok)
-        pos = jnp.asarray(self._pos)
-        drafts = self.draft_lane.draft(tok, pos)  # (B, k)
-        toks = jnp.concatenate([tok[:, None], drafts], axis=1)  # (B, k+1)
-        logits, self.pool = self.engine.paged_verify_step(
-            self.pool, toks, pos, self.tables, task_ids=self._task.copy())
+        with self._span("plan"):
+            # allocate-on-write, widened to the verify's whole write range
+            # pos..pos+k: every page it can touch must be real BEFORE the
+            # tick (the null block would silently swallow accepted KV)
+            for i in occupied:
+                st = self.slots[i]
+                p0 = int(self._pos[i])
+                for j in range(p0 // self.page,
+                               min((p0 + self.spec_k) // self.page,
+                                   st.nb_worst - 1) + 1):
+                    if not self.tables[i, j]:
+                        self.tables[i, j] = self.alloc.alloc()
+                        st.nb_entries += 1
+                        self._reserved -= 1
+            tok = jnp.asarray(self._tok)
+            pos = jnp.asarray(self._pos)
+            task = self._task.copy()
+        with self._span("decode"):
+            drafts = self.draft_lane.draft(tok, pos)  # (B, k)
+            toks = jnp.concatenate([tok[:, None], drafts], axis=1)  # (B, k+1)
+            logits, self.pool = self.engine.paged_verify_step(
+                self.pool, toks, pos, self.tables, task_ids=task)
         self._ticks += 1
-        produced = self._spec_emit(occupied, np.asarray(toks), logits)
-        self._g_free_blocks.set(self.alloc.num_free)
-        self._g_reserved_blocks.set(self._reserved)
-        self._post_tick(t0)
-        return produced
+        return self._spec_emit(occupied, toks, logits)

@@ -15,37 +15,9 @@ from repro.common import tree as tu
 from repro.common.types import ModelCfg, OptimCfg, TrainCfg
 from repro.core import peft
 from repro.models import model as M
+from repro.obs.watchdog import StepWatchdog
 from repro.train import metrics as metrics_mod
 from repro.train.steps import build_eval_step, build_train_step, make_state, merged_params
-
-
-class StepWatchdog:
-    """EWMA step-time tracker: flags straggler steps (the detection signal a
-    cluster scheduler needs for mitigation at real scale)."""
-
-    def __init__(self, factor: float = 2.0, alpha: float = 0.1):
-        self.ewma = None
-        self.factor = factor
-        self.alpha = alpha
-        self.stragglers = []
-
-    def observe(self, step: int, dt: float):
-        if self.ewma is None:
-            self.ewma = dt
-            return False
-        slow = dt > self.factor * self.ewma
-        if slow:
-            self.stragglers.append((step, dt, self.ewma))
-            # clamp the baseline update for flagged steps: folding the
-            # straggler sample itself into the EWMA drags the baseline
-            # toward the pathology, so a run of consecutive stragglers
-            # raises its own detection threshold until it stops firing.
-            # The baseline may still drift up (a real regime change - e.g.
-            # a longer sequence bucket - should eventually be accepted),
-            # but never by more than the flagging threshold per step.
-            dt = self.factor * self.ewma
-        self.ewma = (1 - self.alpha) * self.ewma + self.alpha * dt
-        return slow
 
 
 def _host_metrics(m: Dict) -> Dict[str, float]:

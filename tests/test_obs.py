@@ -8,11 +8,14 @@ snapshot files), per-request trace lifecycle completeness under
 randomized scheduler traffic, the retrace metric catching a genuine
 mid-serve recompile, the ISSUE's acceptance snapshot (one registry,
 mixed spec+paged+multi-tenant serve: quantiles, prefix ratios,
-acceptance rate, bank evictions, zero retraces), and the obs wiring in
-the training loop and profiling helpers.
+acceptance rate, bank evictions, zero retraces), the obs wiring in the
+training loop and profiling helpers, the model step's device scopes, and
+the serving tick's host phase spans (their cover of the tick, slow-tick
+events, the prefill compile count).
 """
 import json
 import math
+import re
 from bisect import bisect_left
 
 import jax
@@ -280,8 +283,8 @@ def test_trace_lifecycle_complete_under_fuzz(serve_kw):
     """Every completed request's trace must tell the whole story: starts
     with submit, admits exactly once (deferred admissions mark `defer`,
     never a second admit), one prefill with a hit kind, first_token
-    present, one `token` mark per emitted token, retire last with the
-    completion's reason - and mark times monotone."""
+    present, retire last with the completion's reason and token count -
+    and mark times monotone."""
     cfg, eng = _world()
     obs = MetricsRegistry()
     sched = make_scheduler(eng, ServingConfig(**serve_kw), obs=obs)
@@ -309,7 +312,8 @@ def test_trace_lifecycle_complete_under_fuzz(serve_kw):
         assert tr.count("admit") == 1
         assert tr.count("prefill") == 1
         assert tr.count("first_token") == 1
-        assert tr.count("token") == len(c.tokens)
+        assert tr.attrs_of("retire")["tokens"] == len(c.tokens)
+        assert tr.count("token") == 0  # no per-token marks
         assert tr.attrs_of("retire")["reason"] == c.finish_reason
         assert tr.attrs_of("admit")["queue_s"] >= 0.0
         kind = tr.attrs_of("prefill")["kind"]
@@ -527,8 +531,13 @@ def test_run_train_reports_into_registry():
 
 
 def test_profile_scope_and_profiled_ticks(tmp_path):
-    from repro.obs.profile import (ProfiledTicks, annotate, profiler_trace,
-                                   scope)
+    """Scopes are transparent; a capture holds the spans of exactly the
+    ticks it covered, on the profiler's clock, with their attrs (those
+    set after the span opened too) as the event's stats."""
+    from jax.profiler import ProfileData
+
+    from repro.obs.profile import (ProfiledTicks, annotate, phase_counters,
+                                   scope, span)
 
     @scope("repro.test_op")
     def f(x):
@@ -537,13 +546,202 @@ def test_profile_scope_and_profiled_ticks(tmp_path):
     assert int(f(jnp.int32(1))) == 2  # named_scope is transparent
     with annotate("tick"):  # no-op outside a capture
         pass
-    with profiler_trace(str(tmp_path / "ctx")):
-        jnp.ones((2,)).block_until_ready()
-    assert list((tmp_path / "ctx").rglob("*"))
 
+    obs = MetricsRegistry()
+    admit = phase_counters(obs, "test", "admit")
     pt = ProfiledTicks(str(tmp_path / "prof"), n=2)
-    for _ in range(4):
-        jnp.zeros((2,)).block_until_ready()
+    for i in range(4):
+        with span(admit, "serve.admit", request_id=i) as s:
+            jnp.zeros((2,)).block_until_ready()
+            s.set_metadata(kind="cold")
         pt.tick()
     pt.stop()  # idempotent after auto-stop at n ticks
-    assert list((tmp_path / "prof").rglob("*")), "no profiler output"
+    files = list((tmp_path / "prof").rglob("*.xplane.pb"))
+    assert files, "no profiler output"
+    events = [e for pl in ProfileData.from_file(str(files[0])).planes
+              for ln in pl.lines for e in ln.events
+              if e.name == "serve.admit"]
+    assert sorted(dict(e.stats)["request_id"] for e in events) == [0, 1]
+    assert all(dict(e.stats)["kind"] == "cold" for e in events)
+    assert obs.counter("serve_phase_calls_total", sched="test",
+                       phase="admit").value == 4
+
+
+_DECODE_SCOPES = ("repro.kv_write", "repro.kv_gather", "repro.attn_core",
+                  "repro.hadamard_adapter", "repro.mlp", "repro.lm_head")
+
+
+def _lowered(program):
+    """Lowered text (with op names) of one of the model's programs at
+    test size: the paged decode step, a prefill, a train step."""
+    from repro.common.types import OptimCfg
+    from repro.core import peft
+    from repro.train.steps import build_train_step, make_state
+
+    cfg = tiny_cfg(adapter=AdapterCfg(kind="hadamard"))
+    params = M.init_params(KEY, cfg)
+    if program == "decode":
+        # five pages of 4: a 20-entry view, padded to kv chunks of 8
+        pool = M.init_paged_pool(cfg, 9, 4, None)
+        low = jax.jit(lambda p, pool, t, pos, tbl: M.decode_lm_paged(
+            p, cfg, pool, t, pos, tbl)).lower(
+            params, pool, jnp.zeros((2, 1), jnp.int32),
+            jnp.zeros((2,), jnp.int32), jnp.zeros((2, 5), jnp.int32))
+    elif program == "prefill":
+        low = jax.jit(lambda p, t: M.prefill_lm(p, cfg, t, cache_len=16)
+                      ).lower(params, jnp.zeros((1, 16), jnp.int32))
+    else:
+        ocfg = OptimCfg()
+        state = make_state(KEY, cfg, peft.strategy("hadamard"), ocfg,
+                           params=params)
+        batch = {"tokens": jnp.zeros((2, 16), jnp.int32),
+                 "labels": jnp.zeros((2, 16), jnp.int32)}
+        low = jax.jit(build_train_step(cfg, ocfg)).lower(state, batch)
+    return low.as_text(debug_info=True)
+
+
+@pytest.mark.parametrize("program,scopes", [
+    ("decode", _DECODE_SCOPES),
+    ("prefill", _DECODE_SCOPES[2:]),
+    ("train", _DECODE_SCOPES[2:]),
+])
+def test_model_scopes_name_the_step_parts(program, scopes):
+    text = _lowered(program)
+    for name in scopes:  # as `repro.x/op`, or `jvp(repro.x)/op` under grad
+        assert re.search(re.escape(name) + r"\)*/", text), (program, name)
+    if program != "decode":  # no cache write or gather outside decode
+        assert "repro.kv_write" not in text
+
+
+def test_span_counts_seconds_and_calls():
+    import time
+
+    from repro.obs.profile import phase_counters, span
+
+    obs = MetricsRegistry()
+    emit = phase_counters(obs, "paged", "emit")
+    for _ in range(3):
+        with span(emit, "serve.emit") as s:
+            time.sleep(0.002)
+        assert s.seconds >= 0.002
+    snap = obs.snapshot()["counters"]
+    assert snap["serve_phase_calls_total{phase=emit,sched=paged}"] == 3
+    assert snap["serve_phase_seconds_total{phase=emit,sched=paged}"] >= 0.006
+
+    off = MetricsRegistry(enabled=False)
+    with span(phase_counters(off, "paged", "emit"), "serve.emit") as s:
+        time.sleep(0.001)
+    assert s.seconds >= 0.001  # the reading is still taken
+    assert off.snapshot()["counters"] == {}
+    assert off.counter("serve_phase_calls_total", sched="paged",
+                       phase="emit").value == 0
+
+
+# ---------------------------------------------------------------------------
+# host phases of the serving tick
+# ---------------------------------------------------------------------------
+
+
+_PHASE_FLAVOURS = [
+    dict(num_slots=2, max_len=32),
+    dict(num_slots=2, max_len=32, paged=True, page_size=8),
+    dict(num_slots=2, max_len=32, spec_k=2),
+    dict(num_slots=2, max_len=32, paged=True, page_size=8, spec_k=2),
+]
+
+
+def _phase_totals(obs, kind):
+    c = obs.snapshot()["counters"]
+    sec = {p: c[f"serve_phase_seconds_total{{phase={p},sched={kind}}}"]
+           for p in ("tick", "admit", "prefill_wait", "plan", "decode",
+                     "sample_wait", "emit")}
+    calls = c[f"serve_phase_calls_total{{phase=tick,sched={kind}}}"]
+    return sec, calls
+
+
+@pytest.mark.parametrize("serve_kw", _PHASE_FLAVOURS,
+                         ids=["contiguous", "paged", "spec", "spec_paged"])
+def test_tick_phases_cover_the_tick(serve_kw):
+    """The disjoint phases (admit, plan, decode, sample_wait, emit) fill
+    90-100% of `serve.tick`, prefill_wait lies inside admit, and every
+    step() call - idle ones too - is one tick span."""
+    cfg, eng = _world()
+    obs = MetricsRegistry()
+    sched = make_scheduler(eng, ServingConfig(**serve_kw), obs=obs)
+    rs = np.random.RandomState(11)
+    sched.run([Request(prompt=rs.randint(0, 97, size=(6,)),
+                       max_new_tokens=2, task_id=i % 2) for i in range(2)])
+    obs.reset()  # compiles are behind us: measure a warm serve
+    sched = make_scheduler(eng, ServingConfig(**serve_kw), obs=obs)
+    for i in range(5):
+        sched.submit(Request(prompt=rs.randint(0, 97, size=(6,)),
+                             max_new_tokens=int(rs.randint(2, 7)),
+                             task_id=i % 2))
+    steps = 0
+    while sched.pending or sched.active:
+        sched.step()
+        steps += 1
+    sched.step()  # an idle tick
+    steps += 1
+    sec, calls = _phase_totals(obs, sched._sched_kind)
+    assert calls == steps
+    parts = sum(sec[p] for p in ("admit", "plan", "decode", "sample_wait",
+                                 "emit"))
+    assert 0.9 * sec["tick"] <= parts <= sec["tick"]
+    assert 0 < sec["prefill_wait"] <= sec["admit"]
+    hist = obs.snapshot()["histograms"][
+        f"serve_tick_s{{sched={sched._sched_kind}}}"]
+    assert hist["count"] == steps
+    assert hist["sum"] == pytest.approx(sec["tick"])
+
+
+def test_slow_tick_event_names_the_phase():
+    """A stream callback that sleeps on one tick makes that tick slow:
+    exactly one `slow_tick` event, its time in `emit`."""
+    import time
+
+    cfg, eng = _world()
+    obs = MetricsRegistry()
+    sleep_at = {"n": 0}
+
+    def stream(rid, tok):
+        sleep_at["n"] += 1
+        if sleep_at["n"] == 30:
+            time.sleep(0.5)
+
+    kw = dict(num_slots=2, max_len=48, paged=True, page_size=8)
+    reqs = [Request(prompt=np.arange(4, dtype=np.int32), max_new_tokens=24,
+                    task_id=i % 2) for i in range(2)]
+    make_scheduler(eng, ServingConfig(**kw)).run(reqs)  # compiles
+    sched = make_scheduler(eng, ServingConfig(**kw, stream=stream), obs=obs)
+    sched.run(reqs)
+    # a busy test machine may flag a short stall elsewhere: the sleep
+    # itself must land in exactly one event
+    events = [e for e in obs.events_of("slow_tick") if e["seconds"] >= 0.5]
+    assert len(events) == 1, obs.events_of("slow_tick")
+    ev = events[0]
+    assert ev["admissions"] == 0
+    assert max(ev["phases"], key=ev["phases"].get) == "emit"
+    assert ev["phases"]["emit"] >= 0.5
+    assert obs.snapshot()["counters"][
+        "serve_slow_ticks_total{sched=paged}"] == len(
+            obs.events_of("slow_tick"))
+
+
+def test_prefill_compiles_are_counted_not_warned(capsys):
+    """A prefill shape first seen mid-serve is counted in
+    `serve_prefill_compiles_total`, with no retrace event or warning."""
+    cfg = tiny_cfg(adapter=AdapterCfg(kind="hadamard"))
+    eng = ServeEngine(cfg, M.init_params(KEY, cfg))
+    obs = MetricsRegistry()
+    sched = make_scheduler(eng, ServingConfig(num_slots=2, max_len=32),
+                           obs=obs)
+    key = "serve_prefill_compiles_total{sched=contiguous}"
+    sched.run([Request(prompt=np.arange(n, dtype=np.int32),
+                       max_new_tokens=3) for n in (4, 5, 4)])
+    assert obs.snapshot()["counters"][key] == 2  # lengths 4 and 5
+    sched.run([Request(prompt=np.arange(5, dtype=np.int32),
+                       max_new_tokens=3)])
+    assert obs.snapshot()["counters"][key] == 2  # no new shape
+    assert obs.events_of("retrace") == []
+    assert "recompiled" not in capsys.readouterr().err

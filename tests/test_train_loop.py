@@ -45,6 +45,24 @@ def test_watchdog_clamp_keeps_flagging_a_straggler_run():
     assert wd.ewma < 5.0
 
 
+def test_watchdog_keeps_a_bounded_record():
+    """A serving process watches ticks forever: the straggler record keeps
+    the newest 1024 flags. The loop's import is the obs class."""
+    from repro.obs import StepWatchdog as ObsWatchdog
+
+    assert StepWatchdog is ObsWatchdog
+    wd = StepWatchdog(factor=2.0, alpha=0.1)
+    wd.observe(0, 1.0)
+    flagged = []
+    for i in range(1, 1101):  # a normal step before each straggler
+        wd.observe(2 * i - 1, 1.0)
+        if wd.observe(2 * i, 100.0):
+            flagged.append(2 * i)
+    assert len(flagged) == 1100
+    assert len(wd.stragglers) == 1024
+    assert [s[0] for s in wd.stragglers] == flagged[-1024:]
+
+
 # ---------------------------------------------------------------------------
 # evaluate memoization
 # ---------------------------------------------------------------------------
