@@ -7,7 +7,10 @@ baselines) reach inside attention without forking the model code.
 Cache protocol (per attention slot):
   train:   cache=None, cache_len=None           -> returns (y, None)
   prefill: cache=None, cache_len=S_cache        -> returns (y, fresh cache)
-  decode:  cache=dict, write_pos=scalar         -> returns (y, updated cache)
+  decode:  cache=dict, write_pos, layer         -> returns (y, updated cache)
+At decode the cache is the layer group's stacked cache (leading `repeats`
+dim, carried through the layer scan): this layer writes at [layer, ...]
+and reads its own rows from there, so the stack is updated in place.
 Cross-attention slots store the encoder K/V at prefill ('ck'/'cv') and read
 them back at decode.
 """
@@ -116,6 +119,7 @@ def apply_attn(
     adapter_cfg: Optional[AdapterCfg] = None,
     block_tables=None,  # paged decode/extend: (B, nbt) physical block ids
     paged_kv_len=None,  # paged extend: traced valid-length override
+    layer=None,  # decode: this layer's index into the stacked cache
 ):
     B, S, _ = x.shape
     H, KH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -181,7 +185,7 @@ def apply_attn(
     new_cache = None
     if is_cross:
         if cache is not None:  # decode: read stored encoder K/V
-            k_att, v_att = cache["ck"], cache["cv"]
+            k_att, v_att = cache["ck"][layer], cache["cv"][layer]
             new_cache = cache
         else:
             k_att, v_att = k, v
@@ -195,7 +199,7 @@ def apply_attn(
 
         pool_k, pool_v = cache["k"], cache["v"]
         vals = pool_k.values if is_qtensor(pool_k) else pool_k
-        page = vals.shape[1]
+        page = vals.shape[2]
         size = block_tables.shape[1] * page  # gathered logical length
         wp = jnp.asarray(write_pos, jnp.int32)
         wp2 = wp if wp.ndim == 2 else wp[:, None]  # (B, S) logical positions
@@ -230,33 +234,35 @@ def apply_attn(
                 mode = "int8" if vals.dtype == jnp.int8 else "fp8"
                 qk = quantize(k, mode, axis=-1)
                 qv = quantize(v, mode, axis=-1)
-                ck = QTensor(pool_k.values.at[blk, off].set(qk.values),
-                             pool_k.scales.at[blk, off].set(qk.scales))
-                cv = QTensor(pool_v.values.at[blk, off].set(qv.values),
-                             pool_v.scales.at[blk, off].set(qv.scales))
+                at = (layer, blk, off)
+                ck = QTensor(pool_k.values.at[at].set(qk.values),
+                             pool_k.scales.at[at].set(qk.scales))
+                cv = QTensor(pool_v.values.at[at].set(qv.values),
+                             pool_v.scales.at[at].set(qv.scales))
             else:
-                ck = pool_k.at[blk, off].set(k.astype(pool_k.dtype))
-                cv = pool_v.at[blk, off].set(v.astype(pool_v.dtype))
+                ck = pool_k.at[layer, blk, off].set(k.astype(pool_k.dtype))
+                cv = pool_v.at[layer, blk, off].set(v.astype(pool_v.dtype))
         new_cache = {"k": ck, "v": cv}
-        k_att = flash.paged_gather(ck, block_tables, cdt)
-        v_att = flash.paged_gather(cv, block_tables, cdt)
+        k_att = flash.paged_gather(ck, layer, block_tables, cdt)
+        v_att = flash.paged_gather(cv, layer, block_tables, cdt)
     elif cache is not None and write_pos is not None:  # self-attn decode
-        size = cache["k"].shape[1]
+        size = cache["k"].shape[2]
         wp = jnp.asarray(write_pos, jnp.int32)
         slot_idx = wp % size
         if wp.ndim == 2:  # (B, S) per-row-per-token: speculative verify
-            bidx = jnp.arange(B)[:, None]
-            ck = cache["k"].at[bidx, slot_idx].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, slot_idx].set(v.astype(cache["v"].dtype))
+            at = (layer, jnp.arange(B)[:, None], slot_idx)
+            ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
+            cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
         elif wp.ndim:  # (B,) per-row write positions (continuous batching)
-            bidx = jnp.arange(B)
-            ck = cache["k"].at[bidx, slot_idx].set(k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[bidx, slot_idx].set(v[:, 0].astype(cache["v"].dtype))
+            at = (layer, jnp.arange(B), slot_idx)
+            ck = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype))
+            cv = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))
         else:
-            ck = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k.astype(cache["k"].dtype), slot_idx, axis=1)
-            cv = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v.astype(cache["v"].dtype), slot_idx, axis=1)
+            at = (layer, 0, slot_idx, 0, 0)
+            ck = jax.lax.dynamic_update_slice(
+                cache["k"], k[None].astype(cache["k"].dtype), at)
+            cv = jax.lax.dynamic_update_slice(
+                cache["v"], v[None].astype(cache["v"].dtype), at)
         new_cache = {"k": ck, "v": cv}
         last = wp[:, -1] if wp.ndim == 2 else wp  # last write per row
         if slot.window is None:
@@ -265,7 +271,7 @@ def apply_attn(
         else:
             kv_pos = ring_positions(size, last)
             eff_len = INVALID_POS  # validity entirely via positions
-        k_att, v_att = ck, cv
+        k_att, v_att = ck[layer], cv[layer]
     elif cache_len is not None:  # self-attn prefill: build the cache
         size = cache_len if slot.window is None else min(slot.window, cache_len)
         kv_pos = q_pos

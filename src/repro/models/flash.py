@@ -340,11 +340,13 @@ def attend(q, k, v, *, q_pos, kv_pos, kv_len=None, causal=True, window=None,
 
 
 @scope("repro.kv_gather")
-def paged_gather(pool, tables, dtype):
+def paged_gather(pool, layer, tables, dtype):
     """Gather a per-sequence contiguous KV view out of a paged block pool.
 
-    pool: (num_blocks, page, KH, Dh) fp32 array, or a QTensor whose values
-    share that shape with per-token-per-head scales (..., KH, 1).
+    pool: a layer group's stacked (repeats, num_blocks, page, KH, Dh) pool,
+    or a QTensor whose values share that shape with per-token-per-head
+    scales (..., KH, 1); `layer` picks the layer. One gather reads
+    pool[layer, tables] without first slicing out pool[layer].
     tables: (B, nbt) int32 physical block ids (entry 0 is the null block -
     its rows are garbage and must be masked by the caller's kv_len /
     position masks). Returns (B, nbt*page, KH, Dh) in `dtype`, dequantized
@@ -355,9 +357,9 @@ def paged_gather(pool, tables, dtype):
     from repro.quant.qtensor import is_qtensor  # deferred: acyclic imports
 
     if is_qtensor(pool):
-        g = (jnp.take(pool.values, tables, axis=0).astype(jnp.float32)
-             * jnp.take(pool.scales, tables, axis=0).astype(jnp.float32))
+        g = (pool.values[layer, tables].astype(jnp.float32)
+             * pool.scales[layer, tables].astype(jnp.float32))
     else:
-        g = jnp.take(pool, tables, axis=0)
+        g = pool[layer, tables]
     B, nbt, page = g.shape[:3]
     return g.reshape(B, nbt * page, *g.shape[3:]).astype(dtype)

@@ -125,10 +125,29 @@ def block_init(key, cfg: ModelCfg, slot: Slot):
 # ---------------------------------------------------------------------------
 
 
+def _layer_of(stacked, layer):
+    """Layer `layer` of a stacked state tree (decode), else the tree."""
+    if layer is None or stacked is None:
+        return stacked
+    return jax.tree.map(lambda a: a[layer], stacked)
+
+
+def _set_layer(stacked, layer, new):
+    """Write one layer's new state into the stacked tree (decode, in
+    place on the scan's carry), else return the new state."""
+    if layer is None:
+        return new
+    return jax.tree.map(lambda s, n: s.at[layer].set(n), stacked, new)
+
+
 def block_apply(p, cfg: ModelCfg, slot: Slot, x, *, q_pos, causal,
                 cache=None, cache_len=None, write_pos=None, enc_out=None,
-                block_tables=None, paged_kv_len=None):
-    """Returns (x, new_cache, aux_loss)."""
+                block_tables=None, paged_kv_len=None, layer=None):
+    """Returns (x, new_cache, aux_loss).
+
+    layer (decode): this block's traced index in its group; `cache` then
+    holds the group's stacked caches, read and written at that index.
+    """
     acfg: AdapterCfg = cfg.adapter
     ad = p.get("adapter")
     aux = jnp.zeros((), jnp.float32)
@@ -139,7 +158,7 @@ def block_apply(p, cfg: ModelCfg, slot: Slot, x, *, q_pos, causal,
         # BERT-style: sublayer -> residual add -> LayerNorm
         a, nc = apply_attn(p["attn"], cfg, slot, x, q_pos=q_pos, causal=causal,
                            cache=c.get("attn"), cache_len=cache_len,
-                           write_pos=write_pos, adapter=ad)
+                           write_pos=write_pos, adapter=ad, layer=layer)
         if ad is not None and acfg.kind == "houlsby":
             a = _houlsby(ad["attn_ad"], a)
         if nc is not None:
@@ -159,17 +178,18 @@ def block_apply(p, cfg: ModelCfg, slot: Slot, x, *, q_pos, causal,
                            cache=c.get("attn"), cache_len=cache_len,
                            write_pos=write_pos, adapter=ad,
                            block_tables=block_tables,
-                           paged_kv_len=paged_kv_len)
+                           paged_kv_len=paged_kv_len, layer=layer)
         if nc is not None:
             new_cache["attn"] = nc
     elif slot.kind == "rec":
-        a, nc = rec_apply(p["rec"], cfg, h, c.get("rec"))
+        a, nc = rec_apply(p["rec"], cfg, h, _layer_of(c.get("rec"), layer))
         if cache_len is not None or cache:
-            new_cache["rec"] = nc
+            new_cache["rec"] = _set_layer(c.get("rec"), layer, nc)
         if ad is not None and acfg.kind == "hadamard":
             a = apply_hadamard(a, ad)  # generalized: affine on mixer output
     else:  # rwkv
-        a, nc_tm = rwkv_time_mix(p["rwkv_tm"], cfg, h, c.get("rwkv"))
+        a, nc_tm = rwkv_time_mix(p["rwkv_tm"], cfg, h,
+                                 _layer_of(c.get("rwkv"), layer))
         if ad is not None and acfg.kind == "hadamard":
             a = apply_hadamard(a, ad)
     if ad is not None and acfg.kind == "houlsby":
@@ -182,16 +202,18 @@ def block_apply(p, cfg: ModelCfg, slot: Slot, x, *, q_pos, causal,
         hc = apply_norm(p["cross_norm"], cfg, x)
         ca, ncc = apply_attn(p["cross"], cfg, slot, hc, q_pos=q_pos, causal=False,
                              kv_x=enc_out, cache=c.get("cross"),
-                             cache_len=cache_len, adapter=None)
+                             cache_len=cache_len, adapter=None, layer=layer)
         if ncc is not None:
             new_cache["cross"] = ncc
         x = x + ca
 
     h = apply_norm(p["ffn_norm"], cfg, x)
     if slot.kind == "rwkv":
-        f, nc_cm = rwkv_channel_mix(p["rwkv_cm"], cfg, h, c.get("rwkv"))
+        f, nc_cm = rwkv_channel_mix(p["rwkv_cm"], cfg, h,
+                                    _layer_of(c.get("rwkv"), layer))
         if cache_len is not None or c.get("rwkv") is not None:
-            new_cache["rwkv"] = {**nc_tm, **nc_cm}
+            new_cache["rwkv"] = _set_layer(c.get("rwkv"), layer,
+                                           {**nc_tm, **nc_cm})
     elif slot.moe:
         f, aux = moe_apply(p["moe"], cfg, h)
     else:
@@ -299,43 +321,53 @@ def group_apply(pg, cfg: ModelCfg, group: Group, x, *, q_pos, causal,
     """Run `repeats` iterations of the slot pattern.
 
     mode: 'train' (no cache), 'prefill' (emit caches), 'decode' (consume +
-    emit caches; S=1, or S>1 for a paged extend).
+    update caches; S=1, or S>1 for a paged extend).
+    Decode carries the group's stacked caches (paged block pools, slot
+    caches, recurrent state) through the scan as part of the carry, with
+    the layer index as a scanned input: each layer scatters its new K/V
+    (or state) into the stacked arrays at [layer, ...] and gathers its
+    view from them there. Scanned as xs/ys they would be rebuilt into a
+    second stacked buffer every call, which donation cannot alias; carried,
+    they are updated in place and the step's output aliases its donated
+    input.
     block_tables (paged decode): one (B, nbt) table shared by every layer,
-    CLOSED OVER by the scan body - the per-layer block pools are what scan
-    slices, the logical->physical mapping is sequence-level state.
+    closed over by the scan body (the logical->physical mapping is
+    sequence-level state).
     Returns (x, new_caches, aux_sum).
     """
+    decode = mode == "decode"
 
     def body(carry, xs):
-        x, aux = carry
-        if mode == "decode":
-            p_layer, cache_layer = xs
-        else:
-            p_layer, cache_layer = xs, None
+        x, aux, stacked = carry  # stacked: the decode caches, else None
+        p_layer, layer = xs if decode else (xs, None)
         new_caches = {}
         for i, slot in enumerate(group.slots):
             x, nc, a = block_apply(
                 p_layer[f"slot{i}"], cfg, slot, x,
                 q_pos=q_pos, causal=causal,
-                cache=(cache_layer or {}).get(f"slot{i}"),
+                cache=(stacked or {}).get(f"slot{i}"),
                 cache_len=cache_len if mode == "prefill" else None,
                 write_pos=write_pos, enc_out=enc_out,
                 block_tables=block_tables, paged_kv_len=paged_kv_len,
+                layer=layer,
             )
             aux = aux + a
             if nc is not None:
                 new_caches[f"slot{i}"] = nc
-        if cfg.sequence_sharding and mode != "decode" and x.shape[1] > 1:
+        if cfg.sequence_sharding and not decode and x.shape[1] > 1:
             x = constrain(x, "dp", "model", None)
-        return (x, aux), (new_caches or None)
+        out = new_caches or None
+        return ((x, aux, out), None) if decode else ((x, aux, None), out)
 
     if cfg.remat and mode == "train":
         body = jax.checkpoint(body, policy=_remat_policy(cfg),
                               prevent_cse=False)
 
-    xs = (pg, caches) if mode == "decode" else pg
-    (x, aux), new_caches = jax.lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), xs,
-        unroll=scan_unroll(group.repeats),
+    xs = (pg, jnp.arange(group.repeats, dtype=jnp.int32)) if decode else pg
+    (x, aux, carried), emitted = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), caches if decode else None),
+        xs, unroll=scan_unroll(group.repeats),
     )
+    return x, (carried if decode else emitted), aux
+    (x, aux), new_caches = jax.lax.scan(body, (x, aux0), pg, unroll=unroll)
     return x, new_caches, aux

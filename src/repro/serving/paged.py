@@ -13,10 +13,11 @@ the repo's stacked-group layer program:
     int8/fp8 QTensors with per-token-per-head scales and the decode path
     dequantizes in-kernel.
   * One block table PER SEQUENCE, shared by every layer: the table maps
-    logical block j -> physical block, and `lax.scan` slices each layer's
-    pool rows while the table rides along unchanged. Tables live on the
-    host as a stable-(num_slots, nb_max)-shaped int32 array, so the fused
-    decode tick compiles exactly once.
+    logical block j -> physical block; the layer scan carries the stacked
+    pool and each layer writes and gathers its own rows in place, while
+    the table rides along unchanged. Tables live on the host as a
+    stable-(num_slots, nb_max)-shaped int32 array, so the fused decode
+    tick compiles exactly once.
   * A refcounted `BlockAllocator` plus a `PrefixCache` keyed by chained
     page hashes of the prompt (per adapter row - the Hadamard adapter
     rewrites K/V, so KV is only shareable between requests on the same

@@ -39,3 +39,16 @@ def tiny_cfg(**kw):
     )
     base.update(kw)
     return ModelCfg(**base)
+
+
+def stacked_groups():
+    """Parameters over layer groups whose decode indexes the stacked caches
+    at several layers and slots, so a read or write at the wrong layer or
+    slot changes the logits: the tiny default, a deeper stack, and two
+    attention slots per layer."""
+    from repro.common.types import Group, Slot
+
+    return [pytest.param(Group((Slot("attn"),), 2), id="repeats2"),
+            pytest.param(Group((Slot("attn"),), 3), id="repeats3"),
+            pytest.param(Group((Slot("attn"), Slot("attn")), 2),
+                         id="two_slots")]

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import tiny_cfg
+from conftest import stacked_groups, tiny_cfg
 from repro.common.types import AdapterCfg, Group, MoECfg, Slot
 from repro.models import model as M
 
@@ -131,8 +131,9 @@ def test_recurrent_families_decode_match_forward(family_cfg):
                                atol=5e-4)
 
 
-def test_multi_step_decode_matches_forward():
-    cfg = tiny_cfg()
+@pytest.mark.parametrize("group", stacked_groups())
+def test_multi_step_decode_matches_forward(group):
+    cfg = tiny_cfg(groups=(group,))
     p = M.init_params(KEY, cfg)
     toks = jax.random.randint(KEY, (2, 16), 0, 97)
     full, _ = M.forward_lm(p, cfg, toks)

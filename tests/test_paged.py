@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import tiny_cfg
+from conftest import stacked_groups, tiny_cfg
 from repro.common.types import Group, Slot
 from repro.kernels import ops, ref
 from repro.models import model as M
@@ -172,17 +172,19 @@ def test_paged_attention_matches_contiguous_gather():
 # ---------------------------------------------------------------------------
 
 
-def _world():
-    cfg = tiny_cfg()
+def _world(**kw):
+    cfg = tiny_cfg(**kw)
     params = M.init_params(KEY, cfg)
     return cfg, params
 
 
-def test_paged_decode_logits_bit_exact():
+@pytest.mark.parametrize("group", stacked_groups())
+def test_paged_decode_logits_bit_exact(group):
     """fp32 paged decode == contiguous decode, logit-for-logit: the
     gathered view has the same length, chunking and masking as the
-    contiguous cache."""
-    cfg, params = _world()
+    contiguous cache. Over several layers and slots per group, a write
+    or gather at the wrong layer or slot of the stacked caches shows."""
+    cfg, params = _world(groups=(group,))
     max_len, page = 32, 8
     prompt = np.asarray(jax.random.randint(KEY, (1, 11), 1, 96))
     eng = ServeEngine(cfg, params)
@@ -207,6 +209,37 @@ def test_paged_decode_logits_bit_exact():
                                            jnp.asarray(pos), tables)
         np.testing.assert_array_equal(np.asarray(lg_c), np.asarray(lg_p))
         tok = np.asarray(jnp.argmax(lg_c[:, -1:], axis=-1), np.int32)
+
+
+@pytest.mark.parametrize("step,kv_quant", [
+    ("decode", None), ("decode", "int8"), ("verify", None), ("extend", None),
+])
+def test_paged_steps_update_the_pool_in_place(step, kv_quant):
+    """The compiled paged decode, verify and extend steps update the
+    donated pool in place, so they hold no pool-sized temp: the layer
+    scan carries the stacked pool rather than rebuilding a second one as
+    its output. float32: the CPU backend widens a bf16 pool to f32, a
+    temp of its own that would hide the result."""
+    cfg, params = _world(groups=(Group((Slot("attn"),), 4),))
+    eng = ServeEngine(cfg, params)
+    B, page, nbt = 4, 8, 4
+    pool = eng.init_paged_pool(num_blocks=1024, page=page, kv_quant=kv_quant)
+    tables = jnp.zeros((B, nbt), jnp.int32)
+    pos = jnp.zeros((B,), jnp.int32)
+    if step == "decode":
+        lowered = eng._decode_paged.lower(
+            eng.params, pool, jnp.zeros((B, 1), jnp.int32), pos, tables)
+    elif step == "verify":
+        lowered = eng._verify_paged.lower(
+            eng.params, pool, jnp.zeros((B, 3), jnp.int32), pos, tables)
+    else:
+        S = 2 * page
+        lowered = eng._extend.lower(
+            eng.params, pool, jnp.zeros((1, S), jnp.int32), tables[:1],
+            jnp.int32(0), jnp.int32(S), jnp.int32(S - 1))
+    temp = lowered.compile().memory_analysis().temp_size_in_bytes
+    pool_bytes = sum(leaf.nbytes for leaf in jax.tree.leaves(pool))
+    assert temp < pool_bytes / 10, (temp, pool_bytes)
 
 
 # ---------------------------------------------------------------------------

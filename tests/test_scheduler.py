@@ -7,7 +7,7 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import tiny_cfg
+from conftest import stacked_groups, tiny_cfg
 from repro.common import tree as tu
 from repro.common.types import AdapterCfg, Group, Slot
 from repro.models import model as M
@@ -23,12 +23,13 @@ def _engine(**kw):
     return ServeEngine(cfg, M.init_params(KEY, cfg)), cfg
 
 
-def test_scheduler_greedy_parity_with_static_engine():
+@pytest.mark.parametrize("group", stacked_groups())
+def test_scheduler_greedy_parity_with_static_engine(group):
     """Token-for-token equal to ServeEngine.generate for the same prompts -
     with num_slots < num_requests, so later requests are admitted into
     slots freed mid-decode and every step mixes requests at different
     positions."""
-    eng, _ = _engine()
+    eng, _ = _engine(groups=(group,))
     toks = np.asarray(jax.random.randint(KEY, (5, 8), 0, 97))
     want = eng.generate(toks, 6)
 

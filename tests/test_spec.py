@@ -24,7 +24,7 @@ import jax
 import numpy as np
 import pytest
 
-from conftest import tiny_cfg
+from conftest import stacked_groups, tiny_cfg
 from repro.common.types import AdapterCfg, Group, Slot
 from repro.core.hadamard import extract_delta, perturb_adapters
 from repro.models import model as M
@@ -36,9 +36,8 @@ from repro.serving import (AdapterBank, AdapterRegistry, DraftLane,
 KEY = jax.random.PRNGKey(11)
 
 
-@pytest.fixture(scope="module")
-def world():
-    cfg = tiny_cfg()
+def _world(**kw):
+    cfg = tiny_cfg(**kw)
     base = M.init_params(KEY, cfg)
     # near-identity task rows: most self-drafts land, some are rejected,
     # so identity checks exercise accept AND reject (untied head - a tied
@@ -46,6 +45,11 @@ def world():
     tasks = [perturb_adapters(base, jax.random.fold_in(KEY, 40 + t),
                               scale=0.01) for t in range(3)]
     return {"cfg": cfg, "base": base, "tasks": tasks}
+
+
+@pytest.fixture(scope="module")
+def world():
+    return _world()
 
 
 def _mixed_reqs(n=6, budget=5):
@@ -70,10 +74,12 @@ def _assert_same_tokens(done_a, done_b):
 # ---------------------------------------------------------------------------
 
 
-def test_spec_token_identity_contiguous_mixed_tenants(world):
+@pytest.mark.parametrize("group", stacked_groups())
+def test_spec_token_identity_contiguous_mixed_tenants(group):
     """Speculative greedy == plain greedy over the contiguous slot pool,
     with 3 adapter rows and one sampled (top_k) tenant sharing every
     tick; verify and draft each compile exactly once."""
+    world = _world(groups=(group,))
     eng = MultiTaskEngine(world["cfg"], world["tasks"])
     plain = make_scheduler(eng, ServingConfig(num_slots=3, max_len=32))
     spec = make_scheduler(eng, ServingConfig(num_slots=3, max_len=32,
@@ -91,11 +97,13 @@ def test_spec_token_identity_contiguous_mixed_tenants(world):
         spec.draft_lane.trace_counts
 
 
-def test_spec_token_identity_paged_with_rejections(world):
+@pytest.mark.parametrize("group", stacked_groups())
+def test_spec_token_identity_paged_with_rejections(group):
     """Speculative greedy == plain greedy over the paged block pool, with
     the rejection path demonstrably exercised: rejected verify positions
     were written into real KV blocks and then overwritten, and no token
     moved."""
+    world = _world(groups=(group,))
     eng = MultiTaskEngine(world["cfg"], world["tasks"])
     serve = dict(num_slots=3, max_len=32, paged=True, page_size=8)
     plain = make_scheduler(eng, ServingConfig(**serve))
