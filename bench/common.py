@@ -1,7 +1,7 @@
 """Pieces every driver shares: the device check, the compile counter, the
 collector's passes, the peaks table, keys from large seeds, raw-sample
-percentiles, and the mapping from a configuration file to the program's
-`ModelCfg`.
+percentiles, and the lookup from a configuration file to its
+architecture's module.
 
 The device check and the compile counter follow `chip_smoke.py`'s
 `device_info` and `CompileClock`; they are kept here so that the
@@ -193,37 +193,19 @@ def percentile(samples, q: float) -> float:
 
 
 # ---------------------------------------------------------------------------
-# configuration file -> the program's ModelCfg
+# configuration file -> its architecture's module
 # ---------------------------------------------------------------------------
 
-ACTIVATIONS = {"silu": "silu", "gelu_tanh": "gelu"}
 
-
-def program_cfg(conf: dict):
-    """The program's ModelCfg for a configuration file: the repo's arch
-    entry with every size the file states put in, and the Hadamard
-    adapter attached."""
-    from repro.common.types import Group, Slot
-    from repro.configs import get
-    from repro.core import peft
-
-    base = get(conf["arch"])
-    kw = dict(
-        d_model=conf["hidden_size"], n_heads=conf["num_attention_heads"],
-        n_kv_heads=conf.get("num_key_value_heads",
-                            conf["num_attention_heads"]),
-        head_dim=conf.get("head_dim", conf["hidden_size"]
-                          // conf["num_attention_heads"]),
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        groups=(Group((Slot("attn"),), conf["num_hidden_layers"]),),
-        act=ACTIVATIONS[conf["hidden_act"]],
-        param_dtype=conf["dtype"]["param"],
-        compute_dtype=conf["dtype"]["compute"])
-    if "rms_norm_eps" in conf:
-        kw.update(norm_eps=conf["rms_norm_eps"], rope_theta=conf["rope_theta"],
-                  tie_embeddings=conf["tie_word_embeddings"])
-    else:
-        kw.update(norm_eps=conf["layer_norm_eps"],
-                  max_seq_len=conf["max_position_embeddings"],
-                  n_segment_types=conf["type_vocab_size"])
-    return peft.attach(base.replace(**kw), peft.strategy("hadamard"))
+def arch(conf: dict, root=ROOT):
+    """The module of the configuration's architecture,
+    `bench/arch/<model_type>.py`: all that the drivers and readers know of
+    a model (the program's `ModelCfg`, the weight layout, the counts of
+    the work a step needs, the plain reference). The only place an
+    architecture is chosen."""
+    name = conf["model_type"]
+    path = pathlib.Path(root) / "bench" / "arch" / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"no architecture module bench/arch/{name}.py for "
+                         f"model_type {name!r}")
+    return load_module(path, f"bench_arch_{name}")

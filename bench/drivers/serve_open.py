@@ -10,10 +10,11 @@ due in it has finished (at most `drain_max_s` more). Latencies run from
 the time a request was due, not from when it was submitted.
 
 Correct: after the window, a seeded sample of the finished requests,
-the longest among them, is run through the plain float32 reference
-(`bench/refs/decoder.py`) over prompt + served tokens; at each served
-token the reference's best logit less that token's logit is the gap,
-and the widest gap over the sample is held to the traffic file's limit.
+the longest among them, is run through the architecture's plain float32
+reference (its module's `served_gaps`) over prompt + served tokens; at
+each served token the reference's best logit less that token's logit is
+the gap, and the widest gap over the sample is held to the traffic
+file's limit.
 Every request due in the window must also have answered: one still
 streaming when the drain ends is late (missing from the latency
 percentiles), one that produced no token never came. The control
@@ -28,8 +29,7 @@ import time
 
 import numpy as np
 
-from bench import common, counts, traffic as gen, weights
-from bench.refs import decoder as ref
+from bench import common, traffic as gen, weights
 from bench.trace import Stretch
 
 
@@ -42,25 +42,12 @@ class Req:
         self.tokens, self.times = [], []
 
 
-def program_shapes(cfg, tenants: int) -> dict:
-    """The program's parameter tree (abstract), adapters banked."""
-    import jax
-    from repro.models import model as M
-
-    flat = weights.flatten(jax.eval_shape(
-        lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)))
-    return {p: (jax.ShapeDtypeStruct(s.shape[:-1] + (tenants, s.shape[-1]),
-                                     s.dtype) if "/adapter/" in p else s)
-            for p, s in flat.items()}
-
-
-def build_engine(conf, cfg, key, tenants: int):
+def build_engine(conf, cfg, layout, key, tenants: int):
     """A MultiTaskEngine over the seed's weights: one task tree per bank
     row, sharing every backbone array."""
     from repro.serving import MultiTaskEngine
 
-    layout = weights.decoder_layout(conf, tenants)
-    weights.check_layout(layout, program_shapes(cfg, tenants))
+    weights.check_layout(layout, weights.program_shapes(cfg, tenants))
     flat = weights.flatten(weights.make(key, layout, conf["initializer_range"]))
     tasks = [weights.nest({p: (v[:, t] if "/adapter/" in p else v)
                            for p, v in flat.items()})
@@ -74,9 +61,10 @@ def run(ctx) -> dict:
 
     conf, tr = ctx.config, ctx.traffic
     T = tr["tenants"]["n"]
-    cfg = common.program_cfg(conf)
-    engine = build_engine(conf, cfg, common.jax_key(ctx.seed, "weights"), T)
-    model = counts.Decoder(conf)
+    layout = ctx.arch.layout(conf, T)
+    engine = build_engine(conf, ctx.arch.program_cfg(conf), layout,
+                          common.jax_key(ctx.seed, "weights"), T)
+    model = ctx.arch.Counts(conf)
 
     reqs: dict = {}          # scheduler request id -> Req
     live: set = set()        # ids between first and last token
@@ -262,10 +250,10 @@ def run(ctx) -> dict:
     chk = tr["check"]
     sample = pick_sample(finished, ctx.seed, chk["served_tokens"],
                          chk["max_requests"])
-    gaps, control = ref.served_gaps(
-        conf, common.jax_key(ctx.seed, "weights"), conf["initializer_range"],
-        T, sample, length=max_len, max_new=tr["output_len"]["max"],
-        control=ctx.control)
+    gaps, control = ctx.arch.served_gaps(
+        conf, layout, common.jax_key(ctx.seed, "weights"),
+        conf["initializer_range"], sample, length=max_len,
+        max_new=tr["output_len"]["max"], control=ctx.control)
     numbers = {}
     for who, g in (("program", gaps), ("control", control)):
         if g is None:
@@ -291,7 +279,7 @@ def run(ctx) -> dict:
         "memory_peak_bytes": memory,
         "trace": trace,
         "record": {
-            "config": conf,
+            "counts": model,
             "window_s": seconds,
             "ticks": ticks,
             "tick_s": tick_s,

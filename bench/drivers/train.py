@@ -12,18 +12,18 @@ on every call, so a window driven through it would build its program
 again inside the window. The window dispatches asynchronously, keeping
 at most two steps in flight, with the device synced at both ends.
 
-Correct: the plain float32 reference (`bench/refs/encoder.py`) runs the
-same three steps from the same weights. The numbers, each the worst over
-the trainable leaves, with the leaves whose reference gradient is under
-a thousandth of the median leaf's left out of the last two: the first
-gradient (Adam's first moment after step 1, over 1 - b1) by
-| |g| - |g_ref| | over max(|g_ref|, the median leaf's |g_ref|) and by
-1 - cos(g, g_ref); the change of each leaf over the three steps by the
-same norm gap; and each step's relative loss gap. The traffic file's
-`check` block names the ones compared and their limits; every number is
-printed. The control (`--control`) is the program on its own bfloat16
-path (the traffic file's `control` block), against the float32
-reference.
+Correct: the architecture's plain float32 reference (its module's
+`train_reference`) runs the same three steps from the same weights. The
+numbers, each the worst over the trainable leaves, with the leaves whose
+reference gradient is under a thousandth of the median leaf's left out
+of the last two: the first gradient (Adam's first moment after step 1,
+over 1 - b1) by | |g| - |g_ref| | over max(|g_ref|, the median leaf's
+|g_ref|) and by 1 - cos(g, g_ref); the change of each leaf over the
+three steps by the same norm gap; and each step's relative loss gap.
+The traffic file's `check` block names the ones compared and their
+limits; every number is printed. The control (`--control`) is the
+program on its own bfloat16 path (the traffic file's `control` block),
+against the float32 reference.
 """
 from __future__ import annotations
 
@@ -34,17 +34,8 @@ import time
 
 import numpy as np
 
-from bench import common, counts, weights
-from bench.refs import encoder as ref
+from bench import common, weights
 from bench.trace import Stretch
-
-
-def program_shapes(cfg) -> dict:
-    import jax
-    from repro.models import model as M
-
-    return weights.flatten(jax.eval_shape(
-        lambda k: M.init_params(k, cfg), jax.random.PRNGKey(0)))
 
 
 def leaves(tree) -> dict:
@@ -82,9 +73,10 @@ def run(ctx) -> dict:
     tr = ctx.traffic
     if ctx.control:
         conf = dict(conf, dtype=dict(conf["dtype"], **tr["control"]["dtype"]))
-    cfg = common.program_cfg(conf)
-    layout = weights.encoder_layout(conf)
-    weights.check_layout(layout, program_shapes(cfg))
+    arch = ctx.arch
+    cfg = arch.program_cfg(conf)
+    layout = arch.layout(conf)
+    weights.check_layout(layout, weights.program_shapes(cfg))
     key = common.jax_key(ctx.seed, "weights")
     o = tr["optim"]
     ocfg = OptimCfg(lr=o["lr"], b1=o["b1"], b2=o["b2"], eps=o["eps"],
@@ -173,7 +165,7 @@ def run(ctx) -> dict:
         if len(seen) > 1:
             outside_steps += len(seen) - 1
             outside_s += seen[-1] - seen[0]
-    flops_tok = counts.Encoder(conf).train_token_flops(S)
+    flops_tok = arch.Counts(conf).train_token_flops(S)
     gap = max(zip(np.diff(done_at), done_at[1:]), default=(0.0, t0))
     ctx.log(f"{n} steps in {window_s:.6f} s; non-finite losses {bad}; "
             f"{outside_steps} steps in {outside_s:.6f} s between completions "
@@ -186,9 +178,9 @@ def run(ctx) -> dict:
     del state, jstep, m, in_flight, window_losses
     gc.collect()
 
-    ref_losses, ref_g1, ref_change = ref.train(
-        stated, key, stated["initializer_range"], [batch(i) for i in range(3)],
-        o)
+    ref_losses, ref_g1, ref_change = arch.train_reference(
+        stated, arch.layout(stated), key, stated["initializer_range"],
+        [batch(i) for i in range(3)], o)
     gnorm = {p: float(np.linalg.norm(v)) for p, v in ref_g1.items()}
     moving = {p for p, v in gnorm.items()
               if v >= 1e-3 * float(np.median(list(gnorm.values())))}
@@ -214,7 +206,7 @@ def run(ctx) -> dict:
         "correct": all(v <= limit for _, v, limit in checks),
         "memory_peak_bytes": memory,
         "trace": trace,
-        "record": {"config": conf, "outside_steps": outside_steps,
+        "record": {"outside_steps": outside_steps,
                    "outside_s": outside_s, "tokens_per_step": B * S,
                    "flops_per_token": flops_tok},
     }

@@ -5,18 +5,16 @@ returns the call that undoes the patch.
 """
 from __future__ import annotations
 
-import itertools
-
 
 def token_altered():
-    """Every 97th served token is replaced by the next token id as the
-    scheduler emits it (the model still decodes the original)."""
+    """The third token of every request is replaced by the next token id
+    as the scheduler emits it (the model still decodes the original)."""
     from repro.serving.scheduler import Scheduler
 
-    emit, count = Scheduler._emit, itertools.count()
+    emit = Scheduler._emit
 
     def altered(self, slot_idx, st, tok):
-        if next(count) % 97 == 50:
+        if len(st.tokens) == 2:  # the request's own count so far
             tok = (tok + 1) % self.engine.cfg.vocab_size
         return emit(self, slot_idx, st, tok)
 

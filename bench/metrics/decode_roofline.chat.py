@@ -4,8 +4,6 @@ the bf16 peak and bytes over HBM bandwidth; bytes are the weights once,
 each active row's live KV below its position and one new KV entry per
 row, with no `max_len` padding), averaged over the traced steps, over
 the step program's mean device time per execution in the trace."""
-from bench import counts
-
 PROGRAM = "jit__pdc"
 
 
@@ -15,7 +13,7 @@ def read(record):
     peak = record["peak"]
     if not times or not steps or not peak:
         return None
-    model = counts.Decoder(record["config"])
+    model = record["counts"]
     least = []
     for positions in steps:
         flops, nbytes = model.decode_step(positions)

@@ -1,8 +1,9 @@
 """Whole train step: tokens trained per second times the FLOPs each
-token needs (`bench/counts.py`: forward, the frozen backbone's
-activation gradients, the trainable leaves' gradients), over the chip's
-bf16 peak. The rate is taken outside the traced stretch, between step
-completions, so the profiler's start and stop do not enter it."""
+token needs (the architecture module's `Counts`: forward, the frozen
+backbone's activation gradients, the trainable leaves' gradients), over
+the chip's bf16 peak. The rate is taken outside the traced stretch,
+between step completions, so the profiler's start and stop do not enter
+it."""
 
 
 def read(record):

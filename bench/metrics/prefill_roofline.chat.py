@@ -4,8 +4,6 @@ with logits for the last position only, against the bf16 peak; bytes of
 the weights once and the KV written, against HBM bandwidth), averaged
 over the traced prefills, over the prefill program's mean device time
 per execution. Bucket padding is work the count does not credit."""
-from bench import counts
-
 PROGRAM = "jit__pf"
 
 
@@ -15,7 +13,7 @@ def read(record):
     peak = record["peak"]
     if not times or not lengths or not peak:
         return None
-    model = counts.Decoder(record["config"])
+    model = record["counts"]
     least = []
     for S in lengths:
         flops, nbytes = model.prefill(S)

@@ -5,9 +5,10 @@ attention, a SiLU-gated MLP, tied embeddings, and the Hadamard adapter
 
 Straight jax.numpy at `highest` matmul precision, no cache, no kernels,
 nothing of the program: the weights are rebuilt from the seed by
-`bench/weights.py`. It runs one layer at a time over rows padded to one
-length, and reads logits only at the positions that predicted a served
-token.
+`bench/weights.py`, from the layout the architecture's module gives. It
+runs one layer at a time, through the layout's groups in order, over
+rows padded to one length, and reads logits only at the positions that
+predicted a served token.
 """
 from __future__ import annotations
 
@@ -18,7 +19,6 @@ import numpy as np
 from bench import weights
 
 F32 = jnp.float32
-P = weights.STACK
 
 
 def rms(x, scale, eps):
@@ -35,64 +35,64 @@ def rope(x, theta):
     return x * jnp.cos(emb) + rot * jnp.sin(emb)
 
 
-def layer(W, l, x, rows, conf):
-    """Layer l of the stacked weights W over x (B, T, d); rows (B,) are
-    the tenants' bank rows."""
+def layer(S, l, x, rows, conf):
+    """Layer l of one group's stacked weights S (keyed by the path inside
+    the slot) over x (B, T, d); rows (B,) are the tenants' bank rows."""
     B, T, d = x.shape
     H, KH, Dh = (conf["num_attention_heads"], conf["num_key_value_heads"],
                  conf["head_dim"])
     eps = conf["rms_norm_eps"]
-    w = {k: v[l] for k, v in W.items() if k.startswith(P)}
-    h = rms(x, w[P + "attn_norm/scale"], eps)
-    q = (h @ w[P + "attn/wq"]).reshape(B, T, H, Dh)
-    k = (h @ w[P + "attn/wk"]).reshape(B, T, KH, Dh)
-    v = (h @ w[P + "attn/wv"]).reshape(B, T, KH, Dh)
-    q = rope(rms(q, w[P + "attn/q_norm"], eps), conf["rope_theta"])
-    k = rope(rms(k, w[P + "attn/k_norm"], eps), conf["rope_theta"])
+    w = {k: v[l] for k, v in S.items()}
+    h = rms(x, w["attn_norm/scale"], eps)
+    q = (h @ w["attn/wq"]).reshape(B, T, H, Dh)
+    k = (h @ w["attn/wk"]).reshape(B, T, KH, Dh)
+    v = (h @ w["attn/wv"]).reshape(B, T, KH, Dh)
+    q = rope(rms(q, w["attn/q_norm"], eps), conf["rope_theta"])
+    k = rope(rms(k, w["attn/k_norm"], eps), conf["rope_theta"])
     k = jnp.repeat(k, H // KH, axis=2)
     v = jnp.repeat(v, H // KH, axis=2)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(Dh)
     causal = jnp.tril(jnp.ones((T, T), bool))
     s = jnp.where(causal, s, -jnp.inf)
     o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
-    a = o.reshape(B, T, H * Dh) @ w[P + "attn/wo"]
-    a = a * w[P + "adapter/w"][rows][:, None] + w[P + "adapter/b"][rows][:, None]
+    a = o.reshape(B, T, H * Dh) @ w["attn/wo"]
+    a = a * w["adapter/w"][rows][:, None] + w["adapter/b"][rows][:, None]
     x = x + a
-    h = rms(x, w[P + "ffn_norm/scale"], eps)
-    f = jax.nn.silu(h @ w[P + "mlp/wi"]) * (h @ w[P + "mlp/wg"])
-    return x + f @ w[P + "mlp/wo"]
+    h = rms(x, w["ffn_norm/scale"], eps)
+    f = jax.nn.silu(h @ w["mlp/wi"]) * (h @ w["mlp/wg"])
+    return x + f @ w["mlp/wo"]
 
 
 MATMULS = ("attn/wq", "attn/wk", "attn/wv", "attn/wo", "mlp/wi", "mlp/wg",
            "mlp/wo")
 
 
-def fp8_weights(W: dict) -> dict:
-    """The matmul weights rounded to float8 e4m3 with one scale per output
-    channel (absmax to 448), back in float32; the rest unchanged."""
+def fp8_weights(S: dict) -> dict:
+    """One group's matmul weights rounded to float8 e4m3 with one scale per
+    output channel (absmax to 448), back in float32; the rest unchanged."""
     def q(w):
         s = jnp.max(jnp.abs(w), axis=-2, keepdims=True) / 448.0
         s = jnp.where(s == 0, 1.0, s)
         return (w / s).astype(jnp.float8_e4m3fn).astype(F32) * s
 
-    return {k: (q(v) if k[len(P):] in MATMULS else v) for k, v in W.items()}
+    return {k: (q(v) if k in MATMULS else v) for k, v in S.items()}
 
 
-def served_gaps(conf: dict, key, std: float, tenants: int, requests,
+def served_gaps(conf: dict, layout: dict, key, std: float, requests,
                 length: int, max_new: int, control: bool = False,
                 batch: int = 4):
-    """For each (prompt, served tokens, tenant): at every served token,
-    the reference's best logit less the logit of the token served. With
-    `control`, also the same reference with float8 matmul weights in the
-    program's place: at the same positions, the reference's best logit
-    less the logit of the token the float8 model puts first. Returns a
-    list of float32 gap arrays per request, and that of the control (or
-    None)."""
-    W = weights.flatten(weights.make(
-        key, weights.decoder_layout(conf, tenants), std, dtype_override=F32))
-    Wc = fp8_weights(W) if control else None
+    """Over the weights `layout` makes from `key`, for each (prompt, served
+    tokens, tenant): at every served token, the reference's best logit
+    less the logit of the token served. With `control`, also the same
+    reference with float8 matmul weights in the program's place: at the
+    same positions, the reference's best logit less the logit of the
+    token the float8 model puts first. Returns a list of float32 gap
+    arrays per request, and that of the control (or None)."""
+    W = weights.flatten(weights.make(key, layout, std, dtype_override=F32))
+    stacks = weights.by_group(W)
+    stacks_c = [fp8_weights(S) for S in stacks] if control else None
     eps = conf["rms_norm_eps"]
-    run_layer = jax.jit(lambda W, l, x, rows: layer(W, l, x, rows, conf))
+    run_layer = jax.jit(lambda S, l, x, rows: layer(S, l, x, rows, conf))
 
     @jax.jit
     def gaps_of(h, hc, at, toks, final_norm, table):
@@ -103,10 +103,11 @@ def served_gaps(conf: dict, key, std: float, tenants: int, requests,
         ctl = jnp.take_along_axis(logits, first[:, None], -1)[:, 0]
         return best - served, best - ctl
 
-    def stack(W, toks, rows):
+    def run(stacks, toks, rows):
         x = W["embed/table"][jnp.asarray(toks)]
-        for l in range(conf["num_hidden_layers"]):
-            x = run_layer(W, jnp.int32(l), x, jnp.asarray(rows))
+        for S in stacks:
+            for l in range(S["attn_norm/scale"].shape[0]):
+                x = run_layer(S, jnp.int32(l), x, jnp.asarray(rows))
         return x
 
     out, out_c = [], []
@@ -119,8 +120,8 @@ def served_gaps(conf: dict, key, std: float, tenants: int, requests,
                 seq = np.concatenate([prompt, served])
                 toks[j, :len(seq)] = seq
                 rows[j] = tenant
-            x = stack(W, toks, rows)
-            xc = stack(Wc, toks, rows) if control else x
+            x = run(stacks, toks, rows)
+            xc = run(stacks_c, toks, rows) if control else x
             for j, (prompt, served, _) in enumerate(chunk):
                 S, n = len(prompt), len(served)
                 at = np.zeros((max_new,), np.int32)

@@ -11,7 +11,8 @@ a global norm, then bias-corrected Adam at a constant learning rate
 without weight decay.
 
 Straight jax.numpy at `highest` matmul precision, nothing of the
-program: the weights are rebuilt from the seed by `bench/weights.py`.
+program: the weights are rebuilt from the seed by `bench/weights.py`,
+from the layout the architecture's module gives.
 """
 from __future__ import annotations
 
@@ -22,7 +23,7 @@ import numpy as np
 from bench import weights
 
 F32 = jnp.float32
-P = weights.STACK
+P = weights.stack()
 TRAINABLE = (P + "adapter/w", P + "adapter/b", P + "ffn_norm/scale",
              P + "ffn_norm/bias")
 
@@ -69,13 +70,12 @@ def loss(W: dict, conf: dict, tokens, type_ids, labels):
     return jnp.mean(lse - picked)
 
 
-def train(conf: dict, key, std: float, batches, optim: dict):
-    """Run len(batches) steps from the seed's weights. Returns (losses,
-    first step's clipped gradient per trainable leaf, parameter change
-    per trainable leaf after the last step), as float32 numpy arrays
-    keyed by path."""
-    W = weights.flatten(weights.make(key, weights.encoder_layout(conf), std,
-                                     dtype_override=F32))
+def train(conf: dict, layout: dict, key, std: float, batches, optim: dict):
+    """Run len(batches) steps from the weights `layout` makes from `key`.
+    Returns (losses, first step's clipped gradient per trainable leaf,
+    parameter change per trainable leaf after the last step), as float32
+    numpy arrays keyed by path."""
+    W = weights.flatten(weights.make(key, layout, std, dtype_override=F32))
     frozen = {k: v for k, v in W.items() if k not in TRAINABLE}
     params = {k: W[k] for k in TRAINABLE}
     b1, b2, eps, lr = optim["b1"], optim["b2"], optim["eps"], optim["lr"]

@@ -3,13 +3,15 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (`BENCHMARK.json` `workloads`) names a configuration and a
-traffic mix. The harness reads the configuration's file, the traffic
-file `bench/traffic/<traffic>.json`, and the driver that file names
-(`bench/drivers/<driver>.py`); with `--trace 1` it reduces a profiler
-trace of a steady stretch and reads each per-layer metric that applies
-to the cell with its own reader, `bench/metrics/<metric>.py`. So a new
-cell, configuration, traffic mix or per-layer metric is new files and
-`BENCHMARK.json` entries, with no edit here.
+traffic mix. The harness reads the configuration's file, the module of
+the architecture its `model_type` names (`bench/arch/<model_type>.py`),
+the traffic file `bench/traffic/<traffic>.json`, and the driver that
+file names (`bench/drivers/<driver>.py`); with `--trace 1` it reduces a
+profiler trace of a steady stretch and reads each per-layer metric that
+applies to the cell with its own reader, `bench/metrics/<metric>.py`.
+So a new cell, configuration, traffic mix or per-layer metric is new
+files and `BENCHMARK.json` entries, with no edit here, and a new
+architecture is a module under `bench/arch/` and a configuration file.
 
 The last stdout line is one JSON object: correct, attempted, failed,
 metrics (the end-to-end metrics, or with `--trace 1` the per-layer
@@ -79,6 +81,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
 
     root = pathlib.Path(root)
     bench, w, conf, traffic = load_cell(root, workload)
+    arch = common.arch(conf, root)
     peaks = common.load_json(root / "bench" / "peaks.json")
     if require_chip:
         device = common.device_info(w["chips"], peaks)
@@ -95,7 +98,7 @@ def run_cell(root, workload: str, seed: int, seconds: float, trace: bool, *,
             root / "bench" / "drivers" / f"{traffic['driver']}.py",
             f"bench_driver_{traffic['driver']}")
         ctx = SimpleNamespace(
-            root=root, config=conf, traffic=traffic, seed=seed,
+            root=root, config=conf, arch=arch, traffic=traffic, seed=seed,
             seconds=seconds, trace=trace, control=control, chips=w["chips"],
             compiles=compiles, gc_passes=gc_passes, t_start=t_start, log=log)
         res = driver.run(ctx)

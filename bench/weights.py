@@ -1,9 +1,10 @@
 """Seeded random weights, made on the device in one jitted call, laid out
 as the program's parameter tree names them.
 
-The layout is written out here from the configuration file alone, so the
-plain references can rebuild the same weights without the program;
-`check_layout` holds it against the program's own tree at set-up.
+Each architecture's module (`bench/arch/<model_type>.py`) writes its
+layout out from the configuration file alone, so the plain references
+can rebuild the same weights without the program; `check_layout` holds
+it against the program's own tree at set-up.
 Matrices and embeddings are N(0, initializer_range) (the Hugging Face
 initialisation), norms 1, biases 0. Hadamard adapters are the identity
 for training; a serving bank gives each tenant w = 1 + 0.05 N(0, 1) and
@@ -16,73 +17,25 @@ import zlib
 import numpy as np
 
 ADAPTER_SCALE = 0.05
-STACK = "blocks/g0/slot0/"
 
 
-def decoder_layout(conf: dict, tenants: int) -> dict:
-    """path -> (shape, dtype, init) for a pre-norm GQA decoder with qk-norm,
-    a gated MLP, tied embeddings and a bank of `tenants` adapters."""
-    L, d = conf["num_hidden_layers"], conf["hidden_size"]
-    H, KH = conf["num_attention_heads"], conf["num_key_value_heads"]
-    Dh, ff, V = conf["head_dim"], conf["intermediate_size"], conf["vocab_size"]
-    p = conf["dtype"]["param"]
-    a = conf["dtype"]["adapter"]
-    out = {
-        "embed/table": ((V, d), p, "normal"),
-        "final_norm/scale": ((d,), p, "ones"),
-        STACK + "attn_norm/scale": ((L, d), p, "ones"),
-        STACK + "ffn_norm/scale": ((L, d), p, "ones"),
-        STACK + "attn/wq": ((L, d, H * Dh), p, "normal"),
-        STACK + "attn/wk": ((L, d, KH * Dh), p, "normal"),
-        STACK + "attn/wv": ((L, d, KH * Dh), p, "normal"),
-        STACK + "attn/wo": ((L, H * Dh, d), p, "normal"),
-        STACK + "attn/q_norm": ((L, Dh), p, "ones"),
-        STACK + "attn/k_norm": ((L, Dh), p, "ones"),
-        STACK + "mlp/wi": ((L, d, ff), p, "normal"),   # gate (under silu)
-        STACK + "mlp/wg": ((L, d, ff), p, "normal"),   # up
-        STACK + "mlp/wo": ((L, ff, d), p, "normal"),   # down
-        STACK + "adapter/w": ((L, tenants, d), a, "tenant_w"),
-        STACK + "adapter/b": ((L, tenants, d), a, "tenant_b"),
-    }
-    return out
+def stack(group: int = 0, slot: int = 0) -> str:
+    """The path prefix of slot `slot`'s stacked leaves in group `group` of
+    the program's layer pattern."""
+    return f"blocks/g{group}/slot{slot}/"
 
 
-def encoder_layout(conf: dict) -> dict:
-    """path -> (shape, dtype, init) for a post-LN BERT/RoBERTa encoder with
-    a pooler, a two-class head and identity Hadamard adapters."""
-    L, d = conf["num_hidden_layers"], conf["hidden_size"]
-    ff, V = conf["intermediate_size"], conf["vocab_size"]
-    P, T = conf["max_position_embeddings"], conf["type_vocab_size"]
-    p = conf["dtype"]["param"]
-    a = conf["dtype"]["adapter"]
-    C = conf["num_labels"]
-    out = {
-        "embed/table": ((V, d), p, "normal"),
-        "pos_embed/table": ((P, d), p, "normal"),
-        "type_embed/table": ((T, d), p, "normal"),
-        "embed_norm/scale": ((d,), p, "ones"),
-        "embed_norm/bias": ((d,), p, "zeros"),
-        "final_norm/scale": ((d,), p, "ones"),
-        "final_norm/bias": ((d,), p, "zeros"),
-        "pooler/kernel": ((d, d), p, "normal"),
-        "pooler/bias": ((d,), p, "zeros"),
-        "classifier/kernel": ((d, C), "float32", "normal"),
-        "classifier/bias": ((C,), "float32", "zeros"),
-        STACK + "adapter/w": ((L, d), a, "ones"),
-        STACK + "adapter/b": ((L, d), a, "zeros"),
-    }
-    for n in ("attn_norm", "ffn_norm"):
-        out[STACK + n + "/scale"] = ((L, d), p, "ones")
-        out[STACK + n + "/bias"] = ((L, d), p, "zeros")
-    for n in ("wq", "wk", "wv", "wo"):
-        out[STACK + "attn/" + n] = ((L, d, d), p, "normal")
-    for n in ("bq", "bk", "bv", "bo"):
-        out[STACK + "attn/" + n] = ((L, d), p, "zeros")
-    out[STACK + "mlp/wi"] = ((L, d, ff), p, "normal")
-    out[STACK + "mlp/wo"] = ((L, ff, d), p, "normal")
-    out[STACK + "mlp/bi"] = ((L, ff), p, "zeros")
-    out[STACK + "mlp/bo"] = ((L, d), p, "zeros")
-    return out
+def by_group(flat: dict, slot: int = 0) -> list:
+    """The stacked leaves of slot `slot` of each group of a flat tree, in
+    the groups' order: one dict per group, keyed by the path inside the
+    slot."""
+    groups: dict = {}
+    for path, v in flat.items():
+        if path.startswith("blocks/"):
+            _, g, s, name = path.split("/", 3)
+            if s == f"slot{slot}":
+                groups.setdefault(int(g[1:]), {})[name] = v
+    return [groups[g] for g in sorted(groups)]
 
 
 def _leaf(key, path, shape, dtype, init, std):
@@ -154,3 +107,18 @@ def check_layout(layout: dict, program_shapes: dict) -> None:
         diff = sorted(map(str, set(want.items()) ^ set(have.items())))
         raise ValueError(f"bench weight layout differs from the program's "
                          f"parameter tree: {diff[:8]}")
+
+
+def program_shapes(cfg, tenants=None) -> dict:
+    """The program's parameter tree for `cfg`, abstract and flat; with
+    `tenants`, each adapter leaf banked as (..., tenants, d)."""
+    import jax
+    from repro.models import model as M
+
+    flat = flatten(jax.eval_shape(lambda k: M.init_params(k, cfg),
+                                  jax.random.PRNGKey(0)))
+    if tenants is None:
+        return flat
+    return {p: (jax.ShapeDtypeStruct(s.shape[:-1] + (tenants, s.shape[-1]),
+                                     s.dtype) if "/adapter/" in p else s)
+            for p, s in flat.items()}

@@ -1,4 +1,5 @@
-"""`bench/counts.py` against counts worked by hand from the published
+"""Each architecture module's `Counts`, found through `common.arch` as
+the drivers find it, against counts worked by hand from the published
 widths."""
 import json
 import pathlib
@@ -8,15 +9,16 @@ REPO = pathlib.Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
     sys.path.append(str(REPO))  # the harness is the package `bench` there
 
-from bench import counts  # noqa: E402
+from bench import common  # noqa: E402
 
 
-def conf(name):
-    return json.loads((REPO / "bench/configs" / f"{name}.json").read_text())
+def counts(name):
+    conf = json.loads((REPO / "bench/configs" / f"{name}.json").read_text())
+    return common.arch(conf).Counts(conf)
 
 
 def test_qwen3_weights_and_kv():
-    m = counts.Decoder(conf("qwen3-0.6b"))
+    m = counts("qwen3-0.6b")
     # per layer: q 1024x2048, k and v 1024x1024 each, o 2048x1024,
     # gate, up, down 1024x3072 each
     assert m.layer_params == 1024 * 4096 + 2048 * 1024 + 3 * 1024 * 3072
@@ -29,7 +31,7 @@ def test_qwen3_weights_and_kv():
 
 
 def test_qwen3_decode_step():
-    m = counts.Decoder(conf("qwen3-0.6b"))
+    m = counts("qwen3-0.6b")
     flops, nbytes = m.decode_step([100, 200])
     # each row needs 2 x (440_401_920 + 155_582_464) FLOPs of matmul and
     # head, and attention 4 x keys x 16 x 128 x 28 over 101 and 201 keys
@@ -42,7 +44,7 @@ def test_qwen3_decode_step():
 
 
 def test_qwen3_prefill():
-    m = counts.Decoder(conf("qwen3-0.6b"))
+    m = counts("qwen3-0.6b")
     flops, nbytes = m.prefill(256)
     # 256 tokens through the layers, the head at the last one, causal
     # attention over 256 x 257 / 2 query-key pairs
@@ -53,7 +55,7 @@ def test_qwen3_prefill():
 
 
 def test_roberta_large_train_token():
-    m = counts.Encoder(conf("roberta-large"))
+    m = counts("roberta-large")
     # q, k, v, o 1024^2 each and the 1024x4096 MLP pair: 302 M weights
     assert 24 * m.layer_params == 301_989_888
     fwd = 2 * 301_989_888 + 4 * 128 * 1024 * 24 + 2 * (1024 * 1024 + 2048) / 128

@@ -1,6 +1,7 @@
 """The harness end to end on the CPU, at the sizes of `make_tiny_root`:
-the look for a chip refuses the CPU, a new cell and a new per-layer
-metric are taken from added files alone, and `correct` comes out false
+the look for a chip refuses the CPU, a new cell, a new per-layer metric
+and a new architecture are taken from added files alone, a configuration
+with no architecture module is refused, and `correct` comes out false
 for the control (one precision lower) and for each fault the cell can
 have, planted under the timed path."""
 import json
@@ -116,6 +117,73 @@ def test_a_new_cell_and_metric_from_added_files_alone(tiny_root, tmp_path):
     assert traced["metrics"]["ticks.chat-slow"]["value"] > 0
     assert "tick_ms.chat" not in traced["metrics"]  # another cell's
     assert list(traced)[-1] == "checks"
+
+
+# An architecture that lays qwen3's layers out in two groups, a leading
+# layer and then the rest, as DeepSeek's `first_k_dense_replace` does.
+LEAD_ARCH = '''"""qwen3 with its first layer in a group of its own."""
+from bench.arch import qwen3
+from bench.arch.qwen3 import Counts, served_gaps  # noqa: F401
+
+
+def groups(conf):
+    return (1, conf["num_hidden_layers"] - 1)
+
+
+def program_cfg(conf):
+    return qwen3.program_cfg(conf, groups(conf))
+
+
+def layout(conf, tenants):
+    return qwen3.layout(conf, tenants, groups(conf))
+'''
+
+
+def test_a_new_architecture_from_added_files_alone(tiny_root, tmp_path):
+    root = tmp_path / "root"
+    shutil.copytree(tiny_root, root, symlinks=True)
+    committed = {p for p in root.rglob("*") if p.is_file()}
+    (root / "bench/arch/qwen3_lead.py").write_text(LEAD_ARCH)
+    conf = json.loads((root / "bench/configs/qwen3-0.6b.json").read_text())
+    conf.update(name="qwen3-lead", model_type="qwen3_lead",
+                num_hidden_layers=3)
+    (root / "bench/configs/qwen3-lead.json").write_text(json.dumps(conf))
+    shutil.copy(root / "bench/traffic/chat.json",
+                root / "bench/traffic/chat-lead.json")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "qwen3-lead", "source": "a test",
+                             "file": "bench/configs/qwen3-lead.json",
+                             "reduced": [], "why": "two groups of layers"})
+    bench["workloads"].append({"name": "qwen3-lead.chat-lead",
+                               "config": "qwen3-lead", "traffic": "chat-lead",
+                               "chips": 1, "why": "a leading layer"})
+    for m in bench["end_to_end"]:
+        if CHAT in m.get("workloads", []):
+            m["workloads"].append("qwen3-lead.chat-lead")
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    # BENCHMARK.json takes entries; every other file is new
+    changed = {p for p in committed if p.name != "BENCHMARK.json"
+               and p.read_bytes() != (tiny_root / p.relative_to(root))
+               .read_bytes()}
+    assert not changed
+
+    from bench import common
+    cfg = common.arch(conf, root).program_cfg(conf)
+    assert [g.repeats for g in cfg.groups] == [1, 2]
+    out = run_tiny(root, "qwen3-lead.chat-lead")
+    assert out["correct"], out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    faulty = run_tiny(root, "qwen3-lead.chat-lead", fault="insert_skipped")
+    assert not faulty["correct"], faulty["checks"]
+
+
+def test_a_configuration_without_its_module_is_refused(tiny_root, tmp_path):
+    root = tmp_path / "root"
+    shutil.copytree(tiny_root, root, symlinks=True)
+    edit(root / "bench/configs/qwen3-0.6b.json", model_type="no_such_arch")
+    with pytest.raises(run.common.BenchError,
+                       match="bench/arch/no_such_arch.py"):
+        run_tiny(root, CHAT)
 
 
 def test_chat_cell_is_correct(tiny_root):
