@@ -56,14 +56,72 @@ class AdapterCfg:
 
 @dataclass(frozen=True)
 class MoECfg:
+    """capacity_factor None routes dropless: every token reaches each of
+    its top_k experts through a grouped matmul over the held experts'
+    sorted assignments, whatever else shares the batch. `held` is
+    (first, count): the experts this chip holds of `n_experts` when each
+    layer is divided over several chips (expert parallelism; dropless
+    only). The router still scores all `n_experts` and takes `top_k`;
+    the layer adds only its held experts' part of the result, and the
+    shared experts in full. None holds every expert."""
+
     n_experts: int
     top_k: int
     d_expert: int
     n_shared: int = 0
-    capacity_factor: float = 1.25
+    capacity_factor: Optional[float] = 1.25
     normalize_weights: bool = True
     router_dtype: str = "float32"
     aux_loss_weight: float = 0.01
+    held: Optional[Tuple[int, int]] = None
+
+    @property
+    def held_range(self) -> Tuple[int, int]:
+        return self.held if self.held is not None else (0, self.n_experts)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA) and YaRN rope
+# ---------------------------------------------------------------------------
+
+
+@dataclass(frozen=True)
+class MLACfg:
+    """Multi-head latent attention (DeepSeek-V2), with an uncompressed
+    query: q is d -> heads x (nope_dim + rope_dim); the keys and values
+    come from a `kv_rank` latent (normed) plus one shared `rope_dim` key
+    part, which is all the cache holds: kv_rank + rope_dim per token per
+    layer. Each head's value is v_dim wide (`ModelCfg.head_dim`)."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def cache_dim(self) -> int:
+        """Width of a cache leaf: latent_dim rounded up to the TPU's 128
+        lanes, the rest zeros (see models/mla.py)."""
+        return -(-self.latent_dim // 128) * 128
+
+
+@dataclass(frozen=True)
+class YarnCfg:
+    """YaRN rope scaling as DeepSeek-V2 publishes it (`rope_scaling` of
+    type 'yarn'): frequencies blend between interpolated (by `factor`)
+    and original over a ramp set by beta_fast/beta_slow rotations within
+    `original_max` positions; the attention scale gains mscale^2."""
+
+    factor: float
+    original_max: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -139,6 +197,8 @@ class ModelCfg:
     rope_theta: float = 10000.0
     max_seq_len: int = 8192
     qk_norm: bool = False
+    mla: Optional[MLACfg] = None  # latent attention (head_dim = v_dim)
+    rope_scaling: Optional[YarnCfg] = None
     attn_softcap: float = 0.0
     final_softcap: float = 0.0
     query_scale: Optional[float] = None  # default 1/sqrt(head_dim)

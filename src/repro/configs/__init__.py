@@ -6,6 +6,7 @@ from repro.common.types import ModelCfg, SHAPES, ShapeSpec  # re-export
 from repro.configs import (
     bert,
     deepseek_moe_16b,
+    deepseek_v2_lite,
     gemma2_27b,
     internvl2_76b,
     qwen3_0_6b,
@@ -17,9 +18,10 @@ from repro.configs import (
     whisper_tiny,
 )
 
-# the 10 assigned architectures
+# the 10 assigned architectures, and those added since for the benchmark
 ASSIGNED = {
     "deepseek-moe-16b": deepseek_moe_16b,
+    "deepseek-v2-lite": deepseek_v2_lite,
     "qwen3-moe-235b-a22b": qwen3_moe_235b_a22b,
     "recurrentgemma-2b": recurrentgemma_2b,
     "whisper-tiny": whisper_tiny,

@@ -11,7 +11,14 @@ under blocks/gN/slotM/...). The policy:
   * expert parallelism: MoE expert-stacked weights (..., E, d, f) put the
     expert dim on 'model' - the (G, E, cap, d) dispatch buffer crossing
     from dp-sharded groups to model-sharded experts is the all-to-all
-    (see models/moe.py).
+    (see models/moe.py). A held share (MoECfg.held) stacks only its
+    experts, under the same rule; the router keeps every expert's
+    column and stays replicated.
+  * latent attention (models/mla.py): wq and the latent's up-projection
+    wkv_b are column-parallel (heads on 'model'), wo row-parallel; the
+    latent's down-projection wkv_a and its norm are replicated, and so
+    is the latent cache: every device reads the whole latent for its
+    own heads.
   * FSDP (shard_profile='tp_fsdp'): large leaves additionally shard one
     free dim over 'data' (weight-gather on use, Zero-3 style).
   * everything else - norms, biases, the paper's Hadamard adapters - is
@@ -54,6 +61,7 @@ _RULES: Tuple[Tuple[re.Pattern, str], ...] = tuple(
         (r"(^|/)lm_head/kernel$", "col"),
         (r"(^|/)vlm_proj/kernel$", "col"),
         (r"/(attn|cross)/(wq|wk|wv)$", "col"),
+        (r"/attn/wkv_b$", "col"),
         (r"/(attn|cross)/wo$", "row"),
         (r"/mlp/(wi|wg)$", "col"),
         (r"/mlp/wo$", "row"),
@@ -301,7 +309,8 @@ def paged_cache_spec(path: str, shape: Sequence[int], cfg, mesh) -> P:
     stays replicated, like the slot dim of `slot_cache_spec`. Model
     parallelism goes on the kv-head dim (head_dim MQA fallback), so every
     device holds the full block table's worth of its head shard and the
-    paged gather stays local.
+    paged gather stays local. A latent leaf (`.../attn/latent`) has no
+    heads to split and stays replicated.
     """
     qt = _QT_LEAF_RE.search(path)
     if qt is not None:

@@ -12,7 +12,9 @@ At decode the cache is the layer group's stacked cache (leading `repeats`
 dim, carried through the layer scan): this layer writes at [layer, ...]
 and reads its own rows from there, so the stack is updated in place.
 Cross-attention slots store the encoder K/V at prefill ('ck'/'cv') and read
-them back at decode.
+them back at decode. A latent-attention config (`cfg.mla`) runs its own
+block (models/mla.py), whose cache is one `latent` leaf under the same
+protocol and the same helpers (`write_read_cache`, `prefill_cache`).
 """
 from __future__ import annotations
 
@@ -25,7 +27,7 @@ from repro.common.types import AdapterCfg, ModelCfg, Slot
 from repro.models import flash
 from repro.models.layers import apply_rope, dense_init, rms_head_norm
 from repro.obs.profile import scope
-from repro.quant.qtensor import qdense
+from repro.quant.qtensor import QTensor, is_qtensor, qdense, quantize
 
 INVALID_POS = jnp.iinfo(jnp.int32).max
 
@@ -36,6 +38,9 @@ INVALID_POS = jnp.iinfo(jnp.int32).max
 
 
 def attn_init(key, cfg: ModelCfg, cross: bool = False):
+    if cfg.mla is not None and not cross:
+        from repro.models.mla import mla_init  # deferred: mla uses the cache helpers below
+        return mla_init(key, cfg)
     ks = jax.random.split(key, 6)
     d, qd, kvd = cfg.d_model, cfg.q_dim, cfg.kv_dim
     p = {
@@ -125,6 +130,20 @@ def apply_attn(
     H, KH, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     acfg = adapter_cfg or cfg.adapter
     cdt = cfg.cdtype
+    if cfg.mla is not None:
+        if kv_x is not None or (adapter is not None and (
+                acfg.kind in ("lora", "ia3") or acfg.position != "attn_out")):
+            raise ValueError("latent attention has no cross-attention and "
+                             "takes only the Hadamard adapter on its output")
+        from repro.models.mla import apply_mla
+        y, new_cache = apply_mla(
+            p, cfg, slot, x, q_pos=q_pos, causal=causal, cache=cache,
+            cache_len=cache_len, write_pos=write_pos,
+            block_tables=block_tables, paged_kv_len=paged_kv_len,
+            layer=layer)
+        if adapter is not None and acfg.kind == "hadamard":
+            y = apply_hadamard(y, adapter)
+        return y, new_cache
     is_cross = kv_x is not None or (cache is not None and "ck" in cache)
 
     q = qdense(x, p["wq"], cdt, tag="attn/wq")
@@ -193,107 +212,16 @@ def apply_attn(
                 new_cache = {"ck": k, "cv": v}
         kv_pos = jnp.arange(k_att.shape[1])
         eff_len = k_att.shape[1]
-    elif (block_tables is not None and cache is not None
-          and write_pos is not None):  # paged decode (S=1) / extend (S>1)
-        from repro.quant.qtensor import QTensor, is_qtensor, quantize
-
-        pool_k, pool_v = cache["k"], cache["v"]
-        vals = pool_k.values if is_qtensor(pool_k) else pool_k
-        page = vals.shape[2]
-        size = block_tables.shape[1] * page  # gathered logical length
-        wp = jnp.asarray(write_pos, jnp.int32)
-        wp2 = wp if wp.ndim == 2 else wp[:, None]  # (B, S) logical positions
-        if slot.window is None:
-            li = wp2
-            kv_pos = jnp.arange(size)
-            if paged_kv_len is not None:
-                eff_len = paged_kv_len
-            else:
-                # (B, S) write_pos is a speculative verify: the valid
-                # length runs to the LAST write, per-query causal masking
-                # hides the later writes from the earlier queries
-                eff_len = (wp[:, -1] if wp.ndim == 2 else wp) + 1
-        else:
-            # ring layout inside the first ring//page table entries; the
-            # gathered tail beyond the ring carries INVALID_POS so validity
-            # is entirely positional (scheduler guarantees page | ring)
-            ring = min(slot.window, size)
-            li = wp2 % ring
-            rp = ring_positions(ring, wp[:, -1] if wp.ndim == 2 else wp)
-            kv_pos = jnp.concatenate(
-                [rp, jnp.full((B, size - ring), INVALID_POS, jnp.int32)],
-                axis=1) if size > ring else rp
-            eff_len = INVALID_POS
-        bidx = jnp.arange(B)[:, None]
-        blk = block_tables[bidx, li // page]  # (B, S) physical blocks
-        off = li % page
-        with scope("repro.kv_write"):
-            if is_qtensor(pool_k):
-                # per-token-per-head scales, computed independently at each
-                # write (absmax over Dh) - matches the pool's scales layout
-                mode = "int8" if vals.dtype == jnp.int8 else "fp8"
-                qk = quantize(k, mode, axis=-1)
-                qv = quantize(v, mode, axis=-1)
-                at = (layer, blk, off)
-                ck = QTensor(pool_k.values.at[at].set(qk.values),
-                             pool_k.scales.at[at].set(qk.scales))
-                cv = QTensor(pool_v.values.at[at].set(qv.values),
-                             pool_v.scales.at[at].set(qv.scales))
-            else:
-                ck = pool_k.at[layer, blk, off].set(k.astype(pool_k.dtype))
-                cv = pool_v.at[layer, blk, off].set(v.astype(pool_v.dtype))
-        new_cache = {"k": ck, "v": cv}
-        k_att = flash.paged_gather(ck, layer, block_tables, cdt)
-        v_att = flash.paged_gather(cv, layer, block_tables, cdt)
     elif cache is not None and write_pos is not None:  # self-attn decode
-        size = cache["k"].shape[2]
-        wp = jnp.asarray(write_pos, jnp.int32)
-        slot_idx = wp % size
-        if wp.ndim == 2:  # (B, S) per-row-per-token: speculative verify
-            at = (layer, jnp.arange(B)[:, None], slot_idx)
-            ck = cache["k"].at[at].set(k.astype(cache["k"].dtype))
-            cv = cache["v"].at[at].set(v.astype(cache["v"].dtype))
-        elif wp.ndim:  # (B,) per-row write positions (continuous batching)
-            at = (layer, jnp.arange(B), slot_idx)
-            ck = cache["k"].at[at].set(k[:, 0].astype(cache["k"].dtype))
-            cv = cache["v"].at[at].set(v[:, 0].astype(cache["v"].dtype))
-        else:
-            at = (layer, 0, slot_idx, 0, 0)
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k[None].astype(cache["k"].dtype), at)
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v[None].astype(cache["v"].dtype), at)
-        new_cache = {"k": ck, "v": cv}
-        last = wp[:, -1] if wp.ndim == 2 else wp  # last write per row
-        if slot.window is None:
-            kv_pos = jnp.arange(size)
-            eff_len = last + 1  # scalar, or (B,) per-row valid lengths
-        else:
-            kv_pos = ring_positions(size, last)
-            eff_len = INVALID_POS  # validity entirely via positions
-        k_att, v_att = ck[layer], cv[layer]
+        new_cache, views, kv_pos, eff_len = write_read_cache(
+            cache, {"k": k, "v": v}, layer, write_pos, window=slot.window,
+            block_tables=block_tables, paged_kv_len=paged_kv_len, dtype=cdt)
+        k_att, v_att = views["k"], views["v"]
     elif cache_len is not None:  # self-attn prefill: build the cache
-        size = cache_len if slot.window is None else min(slot.window, cache_len)
         kv_pos = q_pos
         eff_len = S
         k_att, v_att = k, v
-        if slot.window is None and size == S:
-            new_cache = {"k": k, "v": v}
-        else:
-            tail = min(size, S)
-            zk = jnp.zeros((B, size, KH, Dh), k.dtype)
-            zv = jnp.zeros((B, size, KH, Dh), v.dtype)
-            if slot.window is None:
-                new_cache = {
-                    "k": jax.lax.dynamic_update_slice_in_dim(zk, k[:, S - tail:], S - tail, axis=1),
-                    "v": jax.lax.dynamic_update_slice_in_dim(zv, v[:, S - tail:], S - tail, axis=1),
-                }
-            else:
-                slots = jnp.arange(S - tail, S) % size
-                new_cache = {
-                    "k": zk.at[:, slots].set(k[:, S - tail:]),
-                    "v": zv.at[:, slots].set(v[:, S - tail:]),
-                }
+        new_cache = prefill_cache({"k": k, "v": v}, cache_len, slot.window)
     else:  # train
         kv_pos = q_pos
         eff_len = S
@@ -326,3 +254,121 @@ def apply_attn(
         y = apply_hadamard(y, adapter)
 
     return y, new_cache
+
+
+# ---------------------------------------------------------------------------
+# Cache write and read (shared with latent attention, models/mla.py)
+# ---------------------------------------------------------------------------
+
+
+def write_read_cache(cache, new, layer, write_pos, *, window=None,
+                     block_tables=None, paged_kv_len=None, dtype=None):
+    """Decode's cache step for one attention slot: write the new tokens'
+    entries into the layer group's stacked cache at `layer` and read that
+    layer's view back. Returns (new cache, views, kv_pos, kv_len) for
+    `flash.attend`.
+
+    `new` maps each cache leaf's name to its (B, S, ...) entries: `k` and
+    `v` (B, S, KH, Dh) for GQA, or one `latent` (B, S, mla.cache_dim)
+    that latent attention reads as a single KV head.
+    Paged (`block_tables` (B, nbt)): each leaf is a pool (repeats,
+    num_blocks, page, ...), plain or a QTensor, written through the
+    tables and gathered to (B, nbt*page, ...) in `dtype`; `paged_kv_len`
+    overrides the valid length (extend). Contiguous: each leaf is
+    (repeats, B, size, ...) and is read as stored.
+    write_pos: scalar (all rows), (B,) per-row, or (B, S) per-row-per-
+    token (speculative verify, paged extend) absolute positions. With a
+    `window` the layer keeps a ring of that size (paged: in the first
+    window//page table entries) and validity is entirely positional.
+    """
+    wp = jnp.asarray(write_pos, jnp.int32)
+    if block_tables is not None:
+        first = next(iter(cache.values()))
+        vals = first.values if is_qtensor(first) else first
+        B, page = block_tables.shape[0], vals.shape[2]
+        size = block_tables.shape[1] * page  # gathered logical length
+        wp2 = wp if wp.ndim == 2 else wp[:, None]  # (B, S) logical positions
+        if window is None:
+            li = wp2
+            kv_pos = jnp.arange(size)
+            if paged_kv_len is not None:
+                eff_len = paged_kv_len
+            else:
+                # (B, S) write_pos is a speculative verify: the valid
+                # length runs to the LAST write, per-query causal masking
+                # hides the later writes from the earlier queries
+                eff_len = (wp[:, -1] if wp.ndim == 2 else wp) + 1
+        else:
+            # ring layout inside the first ring//page table entries; the
+            # gathered tail beyond the ring carries INVALID_POS so validity
+            # is entirely positional (scheduler guarantees page | ring)
+            ring = min(window, size)
+            li = wp2 % ring
+            rp = ring_positions(ring, wp[:, -1] if wp.ndim == 2 else wp)
+            kv_pos = jnp.concatenate(
+                [rp, jnp.full((B, size - ring), INVALID_POS, jnp.int32)],
+                axis=1) if size > ring else rp
+            eff_len = INVALID_POS
+        bidx = jnp.arange(B)[:, None]
+        at = (layer, block_tables[bidx, li // page], li % page)
+        with scope("repro.kv_write"):
+            if is_qtensor(first):
+                # per-token-per-head scales, computed independently at each
+                # write (absmax over the last dim) - the pool's scales layout
+                mode = "int8" if vals.dtype == jnp.int8 else "fp8"
+                qs = {n: quantize(x, mode, axis=-1) for n, x in new.items()}
+                out = {n: QTensor(cache[n].values.at[at].set(q.values),
+                                  cache[n].scales.at[at].set(q.scales))
+                       for n, q in qs.items()}
+            else:
+                out = {n: cache[n].at[at].set(x.astype(cache[n].dtype))
+                       for n, x in new.items()}
+        views = {n: flash.paged_gather(out[n], layer, block_tables, dtype)
+                 for n in new}
+        return out, views, kv_pos, eff_len
+
+    size = next(iter(cache.values())).shape[2]
+    slot_idx = wp % size
+    B = next(iter(new.values())).shape[0]
+    with scope("repro.kv_write"):
+        if wp.ndim == 2:  # (B, S) per-row-per-token: speculative verify
+            at = (layer, jnp.arange(B)[:, None], slot_idx)
+            out = {n: cache[n].at[at].set(x.astype(cache[n].dtype))
+                   for n, x in new.items()}
+        elif wp.ndim:  # (B,) per-row write positions (continuous batching)
+            at = (layer, jnp.arange(B), slot_idx)
+            out = {n: cache[n].at[at].set(x[:, 0].astype(cache[n].dtype))
+                   for n, x in new.items()}
+        else:
+            out = {n: jax.lax.dynamic_update_slice(
+                cache[n], x[None].astype(cache[n].dtype),
+                (layer, 0, slot_idx) + (0,) * (x.ndim - 2))
+                for n, x in new.items()}
+    last = wp[:, -1] if wp.ndim == 2 else wp  # last write per row
+    if window is None:
+        kv_pos = jnp.arange(size)
+        eff_len = last + 1  # scalar, or (B,) per-row valid lengths
+    else:
+        kv_pos = ring_positions(size, last)
+        eff_len = INVALID_POS  # validity entirely via positions
+    return out, {n: out[n][layer] for n in new}, kv_pos, eff_len
+
+
+def prefill_cache(new, cache_len: int, window=None):
+    """The cache a prefill of S tokens builds from each leaf's (B, S, ...)
+    entries: (B, size, ...) with size = cache_len, or the window when
+    smaller (a ring, slot p % size holding position p)."""
+    S = next(iter(new.values())).shape[1]
+    size = cache_len if window is None else min(window, cache_len)
+    if window is None and size == S:
+        return dict(new)
+    tail = min(size, S)
+    zeros = {n: jnp.zeros((x.shape[0], size) + x.shape[2:], x.dtype)
+             for n, x in new.items()}
+    if window is None:
+        return {n: jax.lax.dynamic_update_slice_in_dim(
+            zeros[n], x[:, S - tail:], S - tail, axis=1)
+            for n, x in new.items()}
+    slots = jnp.arange(S - tail, S) % size
+    return {n: zeros[n].at[:, slots].set(x[:, S - tail:])
+            for n, x in new.items()}

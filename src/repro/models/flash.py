@@ -9,11 +9,13 @@ oracle both are tested against.
 
 Shapes:
   q:        (B, Sq, KH, G, D)   - G = query heads per kv head
-  k, v:     (B, Skv, KH, D)
+  k, v:     (B, Skv, KH, D), (B, Skv, KH, Dv) - the value width may
+            differ from the key width (latent attention's expanded
+            prefill: 192 vs 128)
   q_pos:    (Sq,) int32 absolute positions of the queries
   kv_pos:   (Skv,) int32 absolute positions of the keys
   kv_len:   scalar int32 - number of valid kv entries (for decode caches)
-Returns:    (B, Sq, KH, G, D)
+Returns:    (B, Sq, KH, G, Dv)
 
 Per-sequence positions (continuous batching): q_pos may be (B, Sq),
 kv_pos (B, Skv) and kv_len (B,) so every row of the batch attends at its
@@ -101,6 +103,7 @@ def _tile_scores(q_i, k_j, scale, cap, tile_dtype=jnp.float32):
 def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
                     q_chunk, kv_chunk, tile_dtype=jnp.float32):
     B, Sq, KH, G, D = q.shape
+    Dv = v.shape[-1]
     Skv = k.shape[1]
     qc = min(q_chunk, Sq)
     kc = min(kv_chunk, Skv)
@@ -114,7 +117,7 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
     )
     q_r = _pad_to(q, nq * qc, 1).reshape(B, nq, qc, KH, G, D).transpose(1, 0, 2, 3, 4, 5)
     k_r = _pad_kv(k, nk * kc).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
-    v_r = _pad_kv(v, nk * kc).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
+    v_r = _pad_kv(v, nk * kc).reshape(B, nk, kc, KH, Dv).transpose(1, 0, 2, 3, 4)
     # chunk-index-leading position tiles: (nq, qc) / (nq, B, qc) etc.
     qp_r = (qp.reshape(B, nq, qc).transpose(1, 0, 2) if q_pos.ndim == 2
             else qp.reshape(nq, qc))
@@ -144,7 +147,7 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
             v_band = jax.lax.dynamic_slice_in_dim(v_flat, start, n_win * kc, 1)
             kp_band = jax.lax.dynamic_slice_in_dim(kp, start, n_win * kc, 0)
             k_it = k_band.reshape(B, n_win, kc, KH, D).transpose(1, 0, 2, 3, 4)
-            v_it = v_band.reshape(B, n_win, kc, KH, D).transpose(1, 0, 2, 3, 4)
+            v_it = v_band.reshape(B, n_win, kc, KH, Dv).transpose(1, 0, 2, 3, 4)
             kp_it = kp_band.reshape(n_win, kc)
         else:
             k_it, v_it, kp_it = k_r, v_r, kp_r
@@ -167,7 +170,7 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
 
         m0 = jnp.full((B, KH, G, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KH, G, qc), jnp.float32)
-        a0 = jnp.zeros((B, KH, G, qc, D), jnp.float32)
+        a0 = jnp.zeros((B, KH, G, qc, Dv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             inner, (m0, l0, a0), (k_it, v_it, kp_it),
             unroll=scan_unroll(n_win if use_band else nk)
@@ -180,7 +183,7 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
     _, (out_r, lse_r) = jax.lax.scan(
         per_q, None, (q_r, qp_r), unroll=scan_unroll(nq)
     )
-    out = out_r.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * qc, KH, G, D)[:, :Sq]
+    out = out_r.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * qc, KH, G, Dv)[:, :Sq]
     lse = lse_r.transpose(1, 2, 3, 0, 4).reshape(B, KH, G, nq * qc)[..., :Sq]
     return out.astype(q.dtype), lse
 
@@ -194,6 +197,7 @@ def _flash_bwd_impl(res, g, causal, window, scale, cap, q_chunk, kv_chunk,
                     tile_dtype=jnp.float32):
     q, k, v, q_pos, kv_pos, kv_len, out, lse = res
     B, Sq, KH, G, D = q.shape
+    Dv = v.shape[-1]
     Skv = k.shape[1]
     qc = min(q_chunk, Sq)
     kc = min(kv_chunk, Skv)
@@ -208,7 +212,7 @@ def _flash_bwd_impl(res, g, causal, window, scale, cap, q_chunk, kv_chunk,
         jnp.iinfo(jnp.int32).max
     )
     q_r = _pad_to(q, nq * qc, 1).reshape(B, nq, qc, KH, G, D).transpose(1, 0, 2, 3, 4, 5)
-    g_r = _pad_to(g, nq * qc, 1).reshape(B, nq, qc, KH, G, D).transpose(1, 0, 2, 3, 4, 5)
+    g_r = _pad_to(g, nq * qc, 1).reshape(B, nq, qc, KH, G, Dv).transpose(1, 0, 2, 3, 4, 5)
     dl_r = (
         _pad_to(delta, nq * qc, 1).reshape(B, nq, qc, KH, G).transpose(1, 0, 2, 3, 4)
     )
@@ -216,7 +220,7 @@ def _flash_bwd_impl(res, g, causal, window, scale, cap, q_chunk, kv_chunk,
         _pad_to(lse, nq * qc, 3).reshape(B, KH, G, nq, qc).transpose(3, 0, 1, 2, 4)
     )
     k_r = _pad_to(k, nk * kc, 1).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
-    v_r = _pad_to(v, nk * kc, 1).reshape(B, nk, kc, KH, D).transpose(1, 0, 2, 3, 4)
+    v_r = _pad_to(v, nk * kc, 1).reshape(B, nk, kc, KH, Dv).transpose(1, 0, 2, 3, 4)
     qp_r = (qp.reshape(B, nq, qc).transpose(1, 0, 2) if q_pos.ndim == 2
             else qp.reshape(nq, qc))
     kp_r = (kp.reshape(B, nk, kc).transpose(1, 0, 2) if kv_pos.ndim == 2
@@ -281,15 +285,16 @@ def _flash_bwd_impl(res, g, causal, window, scale, cap, q_chunk, kv_chunk,
             return (dk_acc, dv_acc), None
 
         z = jnp.zeros((B, kc, KH, D), jnp.float32)
+        zv = z if Dv == D else jnp.zeros((B, kc, KH, Dv), jnp.float32)
         (dk_j, dv_j), _ = jax.lax.scan(
-            inner, (z, z), (q_r, g_r, dl_r, lse_r, qp_r), unroll=scan_unroll(nq)
+            inner, (z, zv), (q_r, g_r, dl_r, lse_r, qp_r), unroll=scan_unroll(nq)
         )
         return None, (dk_j, dv_j)
 
     _, (dk_r, dv_r) = jax.lax.scan(per_kv, None, (k_r, v_r, kp_r),
                                    unroll=scan_unroll(nk))
     dk = dk_r.transpose(1, 0, 2, 3, 4).reshape(B, nk * kc, KH, D)[:, :Skv]
-    dv = dv_r.transpose(1, 0, 2, 3, 4).reshape(B, nk * kc, KH, D)[:, :Skv]
+    dv = dv_r.transpose(1, 0, 2, 3, 4).reshape(B, nk * kc, KH, Dv)[:, :Skv]
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -345,7 +350,10 @@ def paged_gather(pool, layer, tables, dtype):
 
     pool: a layer group's stacked (repeats, num_blocks, page, KH, Dh) pool,
     or a QTensor whose values share that shape with per-token-per-head
-    scales (..., KH, 1); `layer` picks the layer. One gather reads
+    scales (..., KH, 1), or an MLA slot's latent leaf (repeats,
+    num_blocks, page, mla.cache_dim), gathered to (B, nbt*page,
+    mla.cache_dim) for the caller to read as one KV head; `layer`
+    picks the layer. One gather reads
     pool[layer, tables] without first slicing out pool[layer].
     tables: (B, nbt) int32 physical block ids (entry 0 is the null block -
     its rows are garbage and must be masked by the caller's kv_len /

@@ -1,12 +1,13 @@
 """Shared neural-net building blocks (pure functional init/apply pairs)."""
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
-from repro.common.types import ModelCfg
+from repro.common.types import ModelCfg, YarnCfg
 from repro.obs.profile import scope
 from repro.quant.qtensor import qdense
 
@@ -121,13 +122,43 @@ def rope_freqs(head_dim: int, theta: float):
     return 1.0 / (theta**exponent)  # (head_dim/2,)
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq)."""
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """DeepSeek-V2's `yarn_get_mscale`: 0.1 * mscale * ln(scale) + 1."""
+    if scale <= 1:
+        return 1.0
+    return 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(head_dim: int, theta: float, y: YarnCfg):
+    """YaRN inverse frequencies (DeepSeek-V2's DeepseekV2YarnRotaryEmbedding):
+    dims that turn fewer than beta_slow times within the original context
+    are interpolated by `factor`, those that turn more than beta_fast
+    times keep their frequency, with a linear ramp between."""
+    def dim_of(rotations):  # yarn_find_correction_dim
+        return (head_dim * math.log(y.original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(dim_of(y.beta_fast)), 0)
+    high = min(math.ceil(dim_of(y.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extra = rope_freqs(head_dim, theta)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def apply_rope(x, positions, theta: float, freqs=None, mscale: float = 1.0):
+    """x: (..., seq, heads, head_dim); positions: broadcastable to (..., seq).
+    freqs overrides the plain inverse frequencies (YaRN), and mscale
+    scales cos and sin (YaRN's mscale / mscale_all_dim)."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)
+    freqs = rope_freqs(head_dim, theta) if freqs is None else freqs
     angles = positions[..., None].astype(jnp.float32) * freqs  # (..., seq, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., seq, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     y1 = x1 * cos - x2 * sin
     y2 = x2 * cos + x1 * sin

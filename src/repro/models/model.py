@@ -217,6 +217,7 @@ def verify_lm(params, cfg: ModelCfg, caches, tokens, pos):
     one-token decode exactly (the acceptance rule's token-identity
     guarantee). Full-attention slots only: a ring window evicts entries
     the earlier queries still need (the scheduler validates)."""
+    _no_verify_for_mla(cfg)
     pos = jnp.asarray(pos, jnp.int32)
     S = tokens.shape[1]
     qp = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)
@@ -226,6 +227,12 @@ def verify_lm(params, cfg: ModelCfg, caches, tokens, pos):
                                caches=caches, write_pos=qp)
     x = apply_norm(params["final_norm"], cfg, x)
     return lm_logits(params, cfg, x), caches
+
+
+def _no_verify_for_mla(cfg: ModelCfg) -> None:
+    if cfg.mla is not None:
+        raise ValueError("speculative verify is not supported for latent "
+                         "attention (cfg.mla); serve it without spec_k")
 
 
 def init_decode_caches(cfg: ModelCfg, batch: int, cache_len: int):
@@ -242,8 +249,10 @@ def init_decode_caches(cfg: ModelCfg, batch: int, cache_len: int):
 
 def init_paged_pool(cfg: ModelCfg, num_blocks: int, page: int,
                     quant=None):
-    """One device-resident block pool per attention slot; block 0 is the
-    reserved null block (see program.group_pool_init)."""
+    """One device-resident block pool per attention slot: K/V leaves
+    (repeats, num_blocks, page, KH, Dh), or for latent attention one
+    `latent` leaf (repeats, num_blocks, page, mla.cache_dim).
+    Block 0 is the reserved null block (see program.group_pool_init)."""
     return {
         f"g{i}": group_pool_init(cfg, g, num_blocks, page, quant=quant)
         for i, g in enumerate(cfg.groups)
@@ -276,6 +285,7 @@ def verify_lm_paged(params, cfg: ModelCfg, pool, tokens, pos, block_tables):
     gathered view masks by the LAST write's valid length with per-query
     causal masking below it - same rollback-by-overwrite contract as the
     contiguous path."""
+    _no_verify_for_mla(cfg)
     pos = jnp.asarray(pos, jnp.int32)
     S = tokens.shape[1]
     qp = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]  # (B, S)

@@ -2,6 +2,15 @@
 groups + token dropping, MaxText-style argsort routing) plus always-on
 shared experts (DeepSeek-MoE fine-grained layout).
 
+A config with `capacity_factor=None` routes dropless instead
+(`_dropless`): every (token, chosen expert) assignment that falls on an
+expert this chip holds (`MoECfg.held`) is sorted by expert and run
+through grouped matmuls (`jax.lax.ragged_dot`), so no token is dropped
+and a row's output never depends on what else shares its batch. That is
+the expert-parallel layer: the router scores every expert, and the
+chip adds its held experts' part of the result; on one chip there is no
+exchange with the chips that hold the rest.
+
 Sharding story (what makes this scale):
   * tokens are split into G data-aligned groups: (G, T/G, d) with
     P(dp, None, None) - every device routes ITS tokens locally; the
@@ -20,17 +29,18 @@ import jax.numpy as jnp
 from repro.common.types import ModelCfg, MoECfg
 from repro.dist.api import constrain, current_mesh, dp_axes
 from repro.models.layers import act_fn, dense_init
+from repro.obs.profile import scope
 
 
 def moe_init(key, cfg: ModelCfg):
     m = cfg.moe
     ks = jax.random.split(key, 8)
-    d, f, E = cfg.d_model, m.d_expert, m.n_experts
+    d, f, E = cfg.d_model, m.d_expert, m.held_range[1]
     init = lambda k, shape: (
         jax.random.truncated_normal(k, -2.0, 2.0, shape) * 0.02
     ).astype(cfg.pdtype)
     p = {
-        "router": dense_init(ks[0], d, E, jnp.float32),
+        "router": dense_init(ks[0], d, m.n_experts, jnp.float32),
         "wi": init(ks[1], (E, d, f)),
         "wo": init(ks[2], (E, f, d)),
     }
@@ -102,9 +112,73 @@ def _combine_group(rows, info, Tg: int, cdt):
     return jnp.zeros((Tg, d), cdt).at[t_sorted].add(weighted)
 
 
+def _aux_loss(probs, m: MoECfg):
+    """Switch-style load-balancing loss over every expert's probability."""
+    T, E = probs.shape
+    me = probs.mean(axis=0)
+    ce = jnp.zeros((E,), jnp.float32).at[jnp.argmax(probs, axis=-1)].add(1.0) / T
+    return m.aux_loss_weight * E * jnp.sum(me * ce)
+
+
+def _shared(p, cfg: ModelCfg, xf):
+    cdt = cfg.cdtype
+    hs = xf @ p["shared_wi"].astype(cdt)
+    if cfg.gated_mlp:
+        hs = act_fn(cfg.act)(hs) * (xf @ p["shared_wg"].astype(cdt))
+    else:
+        hs = act_fn(cfg.act)(hs)
+    return hs @ p["shared_wo"].astype(cdt)
+
+
+def _dropless(p, cfg: ModelCfg, x):
+    """The held experts' part of every token's top-k mixture, plus the
+    shared experts. Assignments to experts held elsewhere sort after the
+    held ones, outside every group, and add nothing."""
+    m: MoECfg = cfg.moe
+    B, S, d = x.shape
+    T, k = B * S, m.top_k
+    first, n = m.held_range
+    cdt = cfg.cdtype
+    xf = x.reshape(T, d)
+    with scope("repro.moe_route"):
+        probs = jax.nn.softmax(xf.astype(jnp.float32) @ p["router"], axis=-1)
+        gate, idx = jax.lax.top_k(probs, k)  # greedy top-k over all experts
+        if m.normalize_weights:
+            gate = gate / (gate.sum(-1, keepdims=True) + 1e-9)
+        local = (idx - first).reshape(-1)
+        mine = (local >= 0) & (local < n)
+        group = jnp.where(mine, local, n)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+        rows = xf[order // k].astype(cdt)  # (T*k, d), by held expert
+        aux = _aux_loss(probs, m)
+    with scope("repro.moe_experts"):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w.astype(cdt), sizes,
+                                      preferred_element_type=jnp.float32)
+
+        h = act_fn(cfg.act)(grouped(rows, p["wi"]))
+        if cfg.gated_mlp:
+            h = h * grouped(rows, p["wg"])
+        out = grouped(h.astype(cdt), p["wo"])
+        w = gate.reshape(-1)[order]
+        out = jnp.where(mine[order][:, None], out * w[:, None], 0.0)
+        inv = jnp.zeros_like(order).at[order].set(jnp.arange(T * k))
+        y = out[inv].reshape(T, k, d).sum(axis=1).astype(cdt)
+    if "shared_wi" in p:
+        with scope("repro.moe_shared"):
+            y = y + _shared(p, cfg, xf.astype(cdt))
+    return y.reshape(B, S, d), aux
+
+
 def moe_apply(p, cfg: ModelCfg, x):
     """x: (B, S, d). Returns (y, aux_loss)."""
     m: MoECfg = cfg.moe
+    if m.capacity_factor is None:
+        return _dropless(p, cfg, x)
+    if m.held is not None:
+        raise ValueError("a held share of the experts routes dropless "
+                         "(capacity_factor=None)")
     B, S, d = x.shape
     T = B * S
     E, k = m.n_experts, m.top_k
@@ -122,10 +196,7 @@ def moe_apply(p, cfg: ModelCfg, x):
     buf = constrain(buf, "dp", "model", None, None)
 
     # --- aux load-balancing loss (Switch-style, computed globally) ---
-    me = probs.reshape(T, E).mean(axis=0)
-    ce = jnp.zeros((E,), jnp.float32).at[
-        jnp.argmax(probs.reshape(T, E), axis=-1)].add(1.0) / T
-    aux = m.aux_loss_weight * E * jnp.sum(me * ce)
+    aux = _aux_loss(probs.reshape(T, E), m)
 
     # --- expert FFN (batched einsum over groups x experts) ---
     h = jnp.einsum("gecd,edf->gecf", buf, p["wi"].astype(cdt))
@@ -145,12 +216,6 @@ def moe_apply(p, cfg: ModelCfg, x):
 
     # --- shared (always-on) experts ---
     if "shared_wi" in p:
-        xf = x.reshape(T, d)
-        hs = xf @ p["shared_wi"].astype(cdt)
-        if cfg.gated_mlp:
-            hs = act_fn(cfg.act)(hs) * (xf @ p["shared_wg"].astype(cdt))
-        else:
-            hs = act_fn(cfg.act)(hs)
-        y = y + (hs @ p["shared_wo"].astype(cdt)).reshape(B, S, d)
+        y = y + _shared(p, cfg, x.reshape(T, d)).reshape(B, S, d)
 
     return y, aux

@@ -247,7 +247,10 @@ def group_cache_init(cfg: ModelCfg, group: Group, batch: int, cache_len: int,
     """Zeroed stacked cache (used to build decode input specs)."""
     def one_slot(slot: Slot):
         c = {}
-        if slot.kind == "attn":
+        if slot.kind == "attn" and cfg.mla is not None:
+            c["attn"] = {"latent": jnp.zeros(
+                (batch, cache_len, cfg.mla.cache_dim), cfg.cdtype)}
+        elif slot.kind == "attn":
             size = cache_len if slot.window is None else min(slot.window, cache_len)
             c["attn"] = {
                 "k": jnp.zeros((batch, size, cfg.n_kv_heads, cfg.head_dim), cfg.cdtype),
@@ -279,10 +282,14 @@ def group_pool_init(cfg: ModelCfg, group: Group, num_blocks: int, page: int,
     (repeats, num_blocks, page, KH, Dh); with `quant` ('int8'/'fp8') the
     pool leaves are QTensors with per-token-per-head scales
     (repeats, num_blocks, page, KH, 1) - the layout the paged decode path
-    writes with `quantize(k, axis=-1)`. Block 0 is the allocator's
-    reserved null block (unmapped table entries point there and its rows
-    are masked, never read). Paged serving is attention-only: recurrent /
-    rwkv / cross-attention slots have no block-structured state.
+    writes with `quantize(k, axis=-1)`. A latent-attention slot
+    (`cfg.mla`) gets one leaf `latent` of shape
+    (repeats, num_blocks, page, mla.cache_dim) instead (kv_rank +
+    rope_dim values, zero-padded to 128 lanes), never quantized. Block 0
+    is the allocator's reserved null block (unmapped table entries point
+    there and its rows are masked, never read). Paged serving is
+    attention-only: recurrent / rwkv / cross-attention slots have no
+    block-structured state.
     """
     from repro.quant.qtensor import QTensor, _storage_dtype
 
@@ -291,6 +298,14 @@ def group_pool_init(cfg: ModelCfg, group: Group, num_blocks: int, page: int,
             raise ValueError(
                 "paged KV pools require pure attention slots (got "
                 f"kind={slot.kind!r}, cross_attn={slot.cross_attn})")
+    if cfg.mla is not None:
+        if quant:
+            raise ValueError(
+                f"kv_quant={quant!r} is not supported for latent attention: "
+                "its pool holds the latent in the compute dtype")
+        lat = (group.repeats, num_blocks, page, cfg.mla.cache_dim)
+        return {f"slot{i}": {"attn": {"latent": jnp.zeros(lat, cfg.cdtype)}}
+                for i in range(len(group.slots))}
 
     def one_slot():
         kv = (group.repeats, num_blocks, page, cfg.n_kv_heads, cfg.head_dim)

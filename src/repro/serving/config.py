@@ -152,6 +152,9 @@ def make_scheduler(engine, config: ServingConfig, *, draft_model=None,
             f"engine but the engine was built with "
             f"quant={getattr(engine, 'quant', None)!r}")
     draft = None
+    if config.spec_k and engine.cfg.mla is not None:
+        raise ValueError("speculative decoding (spec_k) is not supported "
+                         "for latent attention (cfg.mla)")
     if config.spec_k:
         if config.spec_draft == "model":
             if draft_model is None:

@@ -195,10 +195,12 @@ class ServeEngine:
     def init_paged_pool(self, num_blocks: int, page: int,
                         kv_quant: Optional[str] = None):
         """Zeroed device block pool: (repeats, num_blocks, page, KH, Dh)
-        per attention slot (QTensor leaves under kv_quant). Block 0 is the
-        allocator's reserved null block. Under a mesh the pool is placed
-        with the block dim replicated (kv heads model-sharded) so host-
-        driven block handoffs never trigger collectives."""
+        per attention slot (QTensor leaves under kv_quant; one latent leaf
+        of width mla.cache_dim for latent attention, which refuses
+        kv_quant). Block 0 is the allocator's reserved null block. Under
+        a mesh the pool is placed with the block dim replicated (kv heads
+        model-sharded) so host-driven block handoffs never trigger
+        collectives."""
         pool = M.init_paged_pool(self.cfg, num_blocks, page, quant=kv_quant)
         if self.mesh is not None:
             pool = jax.device_put(
@@ -209,9 +211,11 @@ class ServeEngine:
     def _paged_insert_impl(pool, fresh, ids):
         """Scatter a freshly prefilled contiguous cache (B=1) into pool
         blocks `ids`. fresh leaves (R, 1, nbl*page, KH, Dh) are repaged to
-        (R, nbl, page, KH, Dh); QTensor pools quantize per page-token on
-        the way in (absmax over Dh - the same independent-per-write rule
-        the decode path uses, so extend/prefill agree bit-for-bit)."""
+        (R, nbl, page, KH, Dh), and a latent leaf (R, 1, nbl*page, width)
+        to (R, nbl, page, width) alike; QTensor pools quantize per
+        page-token on the way in (absmax over Dh - the same
+        independent-per-write rule the decode path uses, so
+        extend/prefill agree bit-for-bit)."""
         from repro.quant.qtensor import QTensor, is_qtensor, quantize
 
         def one(dst, src):
@@ -233,7 +237,8 @@ class ServeEngine:
 
     @staticmethod
     def _copy_block_impl(pool, src, dst):
-        """COW fork: duplicate physical block src into dst on every leaf."""
+        """COW fork: duplicate physical block src into dst on every leaf
+        (K/V, QTensor components or the latent)."""
         from repro.quant.qtensor import QTensor, is_qtensor
 
         def one(leaf):

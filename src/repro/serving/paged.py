@@ -11,7 +11,9 @@ the repo's stacked-group layer program:
     block (unallocated table entries point at it; reads of it are always
     masked, writes to it are harmless). Under `kv_quant` the leaves are
     int8/fp8 QTensors with per-token-per-head scales and the decode path
-    dequantizes in-kernel.
+    dequantizes in-kernel. A latent-attention slot has one leaf instead,
+    (repeats, num_blocks, page, mla.cache_dim), never quantized;
+    the block accounting is the same (blocks per token).
   * One block table PER SEQUENCE, shared by every layer: the table maps
     logical block j -> physical block; the layer scan carries the stacked
     pool and each layer writes and gathers its own rows in place, while
@@ -238,8 +240,10 @@ class PagedScheduler(Scheduler):
             for s in g.slots:
                 if s.kind != "attn" or s.cross_attn:
                     raise ValueError(
-                        "PagedScheduler requires pure attention slots "
-                        f"(got kind={s.kind!r} cross={s.cross_attn})")
+                        "PagedScheduler accepts self-attention slots only "
+                        "(kind='attn', full, windowed or latent, with a "
+                        f"dense or MoE FFN); got kind={s.kind!r} "
+                        f"cross_attn={s.cross_attn}")
                 if s.window is not None and min(s.window, max_len) % page:
                     raise ValueError(
                         f"windowed slot ring {min(s.window, max_len)} must "

@@ -72,6 +72,7 @@ def test_full_config_matches_spec(arch):
     cfg = get(arch)
     spec = {
         "deepseek-moe-16b": dict(L=28, d=2048, H=16, kv=16, vocab=102400),
+        "deepseek-v2-lite": dict(L=27, d=2048, H=16, kv=16, vocab=102400),
         "qwen3-moe-235b-a22b": dict(L=94, d=4096, H=64, kv=4, vocab=151936),
         "recurrentgemma-2b": dict(L=26, d=2560, H=10, kv=1, vocab=256000),
         "whisper-tiny": dict(L=8, d=384, H=6, kv=6, vocab=51865),
@@ -90,7 +91,8 @@ def test_full_config_matches_spec(arch):
 
 
 @pytest.mark.parametrize("arch,n_b", [
-    ("deepseek-moe-16b", 16.4e9), ("qwen3-moe-235b-a22b", 235e9),
+    ("deepseek-moe-16b", 16.4e9), ("deepseek-v2-lite", 15.7e9),
+    ("qwen3-moe-235b-a22b", 235e9),
     ("gemma2-27b", 27e9), ("internvl2-76b", 76e9),
     ("starcoder2-7b", 7e9), ("starcoder2-3b", 3e9),
     ("rwkv6-1.6b", 1.6e9), ("recurrentgemma-2b", 2.7e9),
@@ -107,7 +109,8 @@ def test_full_param_counts_in_range(arch, n_b):
 
 
 @pytest.mark.parametrize("arch", ["qwen3-0.6b", "rwkv6-1.6b",
-                                  "recurrentgemma-2b", "whisper-tiny"])
+                                  "recurrentgemma-2b", "whisper-tiny",
+                                  "deepseek-v2-lite"])
 def test_smoke_decode_step(arch):
     cfg = peft.attach(get_smoke(arch), peft.strategy("hadamard"))
     p = M.init_params(KEY, cfg)

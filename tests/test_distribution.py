@@ -86,6 +86,75 @@ def test_cache_spec_heads_vs_headdim():
     assert spec == P(None, "data", None, None, "model")
 
 
+def test_param_and_pool_specs_for_latent_attention_and_held_experts():
+    """MLA: q and the latent's up-projection column-parallel (heads on
+    'model'), o row-parallel, the down-projection, its norm and the
+    latent pool replicated; a held share of the experts shards its own
+    expert dim, the router keeps every expert's column, replicated."""
+    from repro.dist.sharding import paged_cache_spec
+
+    cfg = get_cfg("deepseek-v2-lite")
+    mesh = FakeMesh()
+    S = "blocks/g1/slot0/"
+    assert param_spec(S + "attn/wq", (26, 2048, 3072), cfg, mesh) \
+        == P(None, None, "model")
+    assert param_spec(S + "attn/wkv_b", (26, 512, 4096), cfg, mesh) \
+        == P(None, None, "model")
+    assert param_spec(S + "attn/wo", (26, 2048, 2048), cfg, mesh) \
+        == P(None, "model", None)
+    assert param_spec(S + "attn/wkv_a", (26, 2048, 576), cfg, mesh) == P()
+    assert param_spec(S + "attn/kv_norm", (26, 512), cfg, mesh) == P()
+    assert param_spec(S + "moe/wi", (26, 8, 2048, 1408), cfg, mesh) \
+        == P(None, "model", None, None)
+    assert param_spec(S + "moe/router", (26, 2048, 64), cfg, mesh) == P()
+    assert paged_cache_spec("g1/slot0/attn/latent", (26, 12289, 16, 640),
+                            cfg, mesh) == P(None, None, None, None)
+
+
+def test_meshed_engine_serves_latent_attention_and_held_experts():
+    """A MultiTaskEngine for DeepSeek-V2 (smoke size, 4 of 8 experts held)
+    builds on a (2, 4) mesh with the rules above and serves through the
+    paged scheduler the same greedy tokens as on one device."""
+    code = """
+import dataclasses
+import jax, numpy as np
+from jax.sharding import AxisType, PartitionSpec as P
+from repro.configs import get_smoke
+from repro.core import peft
+from repro.dist.api import use_mesh
+from repro.models import model as M
+from repro.serving import MultiTaskEngine, Request, ServingConfig, make_scheduler
+
+cfg = peft.attach(get_smoke('deepseek-v2-lite'), peft.strategy('hadamard'))
+cfg = cfg.replace(moe=dataclasses.replace(cfg.moe, held=(2, 4)))
+params = M.init_params(jax.random.PRNGKey(0), cfg)
+other = jax.tree_util.tree_map_with_path(
+    lambda p, v: v * 1.1 if 'adapter' in jax.tree_util.keystr(p) else v, params)
+prompts = [np.arange(3, 14, dtype=np.int32), np.arange(20, 25, dtype=np.int32)]
+
+def serve():
+    eng = MultiTaskEngine(cfg, [params, other])
+    sched = make_scheduler(eng, ServingConfig(
+        paged=True, page_size=8, num_slots=2, max_len=32, num_blocks=16))
+    done, _ = sched.run([Request(prompt=p, max_new_tokens=6, task_id=t)
+                         for t, p in enumerate(prompts)])
+    return [list(map(int, c.tokens)) for c in done], eng, sched
+
+one, _, _ = serve()
+mesh = jax.make_mesh((2, 4), ('data', 'model'),
+                     axis_types=(AxisType.Auto, AxisType.Auto))
+with use_mesh(mesh):
+    meshed, eng, sched = serve()
+blk = eng.bank['blocks']['g1']['slot0']
+assert blk['attn']['wkv_b'].sharding.spec == P(None, None, 'model'), blk['attn']['wkv_b'].sharding
+assert blk['moe']['wi'].sharding.spec == P(None, 'model', None, None)
+assert not any(sched.pool['g1']['slot0']['attn']['latent'].sharding.spec)
+assert one == meshed, (one, meshed)
+print('MESH-OK', one)
+"""
+    assert "MESH-OK" in _run(code)
+
+
 # ---------------------------------------------------------------------------
 # subprocess multi-device integration
 # ---------------------------------------------------------------------------

@@ -29,7 +29,7 @@ from repro.quant.qtensor import quantize
 from repro.serving import ServingConfig, make_scheduler
 from repro.serving.engine import ServeEngine
 from repro.serving.paged import (BlockAllocator, BlockPoolFullError,
-                                 PrefixCache)
+                                 PagedScheduler, PrefixCache)
 from repro.serving.scheduler import Request
 
 KEY = jax.random.PRNGKey(42)
@@ -84,6 +84,18 @@ def test_allocator_refcount_discipline(seed, num_blocks):
             alloc.decref(bid)
         with pytest.raises(ValueError):
             alloc.incref(bid)
+
+
+@pytest.mark.parametrize("slot", [Slot("rec"), Slot("attn", cross_attn=True)],
+                         ids=["recurrent", "cross"])
+def test_paged_scheduler_says_which_slots_it_accepts(slot):
+    from types import SimpleNamespace
+
+    cfg = tiny_cfg(groups=(Group((Slot("attn"), slot), 1),))
+    with pytest.raises(ValueError, match=r"accepts self-attention slots only "
+                       r"\(kind='attn', full, windowed or latent"):
+        PagedScheduler(SimpleNamespace(cfg=cfg), num_slots=2, num_blocks=8,
+                       page=4, max_len=16)
 
 
 def test_prefix_cache_eviction_releases_blocks():
