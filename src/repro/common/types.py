@@ -235,7 +235,8 @@ class ModelCfg:
     # remat policy: 'none' = nothing saveable; 'dots' = save matmul outputs
     # (compute-vs-memory lever: skips the fwd recompute in backward)
     remat_policy: str = "none"
-    # attention chunking (flash-style jnp path)
+    # attention chunking (flash-style jnp path): the q tile, and a
+    # q_chunk x kv_chunk budget for a tile's area (flash._kv_tile)
     q_chunk: int = 512
     kv_chunk: int = 1024
     # perf levers (§Perf; default OFF = paper-faithful baseline)

@@ -35,11 +35,6 @@ def config() -> ModelCfg:
         pos="rope",
         rope_theta=10000.0,
         max_seq_len=163840,
-        # decode reads the latent as one KV head, so a whole view's scores
-        # (B x 16 heads x L, f32) are small: one kv chunk up to 4096
-        # positions. The default 1024 pads a 1536-position view to 2048
-        # and slices it in two on every decode step.
-        kv_chunk=4096,
         shard_profile="tp",
     )
 

@@ -49,12 +49,26 @@ def _pad_to(x, size: int, axis: int):
     return jnp.pad(x, widths)
 
 
+def _kv_tile(Sq: int, Skv: int, q_chunk: int, kv_chunk: int):
+    """The (q, kv) chunk lengths of a flash call. `q_chunk x kv_chunk` is
+    a budget for a tile's area, not a cap on its kv length: when one q
+    chunk's scores over every key fit it, the keys are one chunk, so K and
+    V are neither padded to whole chunks nor sliced into them. That is a
+    decode or verify step over its whole cache view (a 1 x 1536 view under
+    the default 512 x 1024), and every prefill or train step whose keys
+    are one kv_chunk or fewer. Longer keys go in kv_chunk pieces."""
+    qc = min(q_chunk, Sq)
+    kc = Skv if qc * Skv <= q_chunk * kv_chunk else kv_chunk
+    return qc, kc
+
+
 def _pad_kv(x, size: int):
-    """K or V padded along the sequence to whole kv chunks. Over a decode's
-    max_len cache view this copies the whole view, so the pad is named
-    with the gather that built the view (`repro.kv_gather`); a prefill or
-    train step whose length is not a whole number of chunks shows its pad
-    under that name too."""
+    """K or V padded along the sequence to whole kv chunks: only when the
+    keys take several chunks (`_kv_tile`) and their length is not a whole
+    number of them. A decode view past the tile budget copies the whole
+    view here, so the pad is named with the gather that built the view
+    (`repro.kv_gather`); a prefill or train step shows its pad under that
+    name too."""
     with scope("repro.kv_gather"):
         return _pad_to(x, size, 1)
 
@@ -105,8 +119,7 @@ def _flash_fwd_impl(q, k, v, q_pos, kv_pos, kv_len, causal, window, scale, cap,
     B, Sq, KH, G, D = q.shape
     Dv = v.shape[-1]
     Skv = k.shape[1]
-    qc = min(q_chunk, Sq)
-    kc = min(kv_chunk, Skv)
+    qc, kc = _kv_tile(Sq, Skv, q_chunk, kv_chunk)
     nq, nk = _cdiv(Sq, qc), _cdiv(Skv, kc)
 
     batched_pos = q_pos.ndim == 2 or kv_pos.ndim == 2
@@ -199,8 +212,7 @@ def _flash_bwd_impl(res, g, causal, window, scale, cap, q_chunk, kv_chunk,
     B, Sq, KH, G, D = q.shape
     Dv = v.shape[-1]
     Skv = k.shape[1]
-    qc = min(q_chunk, Sq)
-    kc = min(kv_chunk, Skv)
+    qc, kc = _kv_tile(Sq, Skv, q_chunk, kv_chunk)
     nq, nk = _cdiv(Sq, qc), _cdiv(Skv, kc)
 
     g = g.astype(jnp.float32)

@@ -264,9 +264,10 @@ def decode_lm_paged(params, cfg: ModelCfg, pool, token, pos, block_tables):
     pool blocks addressed through its `block_tables` row (B, nbt). pos is
     (B,) per-row absolute positions; rows whose table is all-null (free
     slots) write into block 0 and their logits are garbage the scheduler
-    ignores. nbt*page must equal the contiguous cache length it replaces
-    so the flash kv-chunk decomposition (and therefore every fp32 token)
-    is identical."""
+    ignores. nbt*page must equal the contiguous cache length it replaces:
+    both views then take the same kv chunks (`flash._kv_tile`: one chunk
+    while 1 x the view fits the q_chunk x kv_chunk budget), so every fp32
+    token is identical."""
     pos = jnp.asarray(pos, jnp.int32)
     x = embed_tokens(params, cfg, token)
     q_pos = pos[:, None] if pos.ndim else jnp.full((1,), pos, jnp.int32)

@@ -52,3 +52,17 @@ def stacked_groups():
             pytest.param(Group((Slot("attn"),), 3), id="repeats3"),
             pytest.param(Group((Slot("attn"), Slot("attn")), 2),
                          id="two_slots")]
+
+
+def record_kv_tiles(monkeypatch):
+    """A list that collects ((Sq, Skv), (q chunk, kv chunk)) for every
+    flash call traced from here on (`flash._kv_tile`'s choice)."""
+    from repro.models import flash
+
+    tiles, choose = [], flash._kv_tile
+
+    def spy(*a):
+        tiles.append((a[:2], choose(*a)))
+        return tiles[-1][1]
+    monkeypatch.setattr(flash, "_kv_tile", spy)
+    return tiles

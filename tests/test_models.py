@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import stacked_groups, tiny_cfg
+from conftest import record_kv_tiles, stacked_groups, tiny_cfg
 from repro.common.types import AdapterCfg, Group, MoECfg, Slot
 from repro.models import model as M
 
@@ -185,12 +185,64 @@ def test_windowed_attention_limits_range():
                                atol=1e-5)
 
 
-def test_flash_chunking_invariance():
-    """Different chunk sizes produce identical logits."""
+def _decode_logits(p, cfg, toks, prompt: int, cache_len: int):
+    """Prefill `prompt` tokens into a `cache_len` cache, then decode the
+    rest of `toks` one at a time (Sq = 1): every step's logits."""
+    _, caches = M.prefill_lm(p, cfg, toks[:, :prompt], cache_len=cache_len)
+    out = []
+    for t in range(prompt, toks.shape[1]):
+        lg, caches = M.decode_lm(p, cfg, caches, toks[:, t:t + 1], jnp.int32(t))
+        out.append(lg[:, 0])
+    return jnp.stack(out, 1)
+
+
+@pytest.mark.parametrize("step", ["forward", "decode"])
+def test_flash_chunking_invariance(step, monkeypatch):
+    """Different chunk sizes produce identical logits. `decode` scores one
+    query (Sq = 1) over a 40-position cache past the small config's
+    2 x 8 tile budget, so it still runs the online softmax over several
+    kv chunks; the large config's reference takes the view in one."""
     toks = jax.random.randint(KEY, (1, 24), 0, 97)
-    cfg8 = tiny_cfg(q_chunk=8, kv_chunk=8)
-    cfg64 = tiny_cfg(q_chunk=64, kv_chunk=64)
-    p = M.init_params(KEY, cfg8)
-    l8, _ = M.forward_lm(p, cfg8, toks)
-    l64, _ = M.forward_lm(p, cfg64, toks)
-    np.testing.assert_allclose(np.asarray(l8), np.asarray(l64), atol=2e-4)
+    small = tiny_cfg(q_chunk=8, kv_chunk=8)
+    big = tiny_cfg(q_chunk=64, kv_chunk=64)
+    if step == "decode":
+        small = tiny_cfg(q_chunk=2, kv_chunk=8)
+        tiles = record_kv_tiles(monkeypatch)
+    p = M.init_params(KEY, small)
+    if step == "forward":
+        ls, _ = M.forward_lm(p, small, toks)
+        lb, _ = M.forward_lm(p, big, toks)
+    else:
+        ls = _decode_logits(p, small, toks, 16, 40)
+        assert {t for t in tiles if t[0][0] == 1} == {((1, 40), (1, 8))}
+        tiles.clear()
+        lb = _decode_logits(p, big, toks, 16, 40)
+        assert {t for t in tiles if t[0][0] == 1} == {((1, 40), (1, 40))}
+    np.testing.assert_allclose(np.asarray(ls), np.asarray(lb), atol=2e-4)
+
+
+@pytest.mark.parametrize("arch,Sq,Skv,want", [
+    pytest.param("qwen3-0.6b", 1, 1536, (1, 1536), id="decode_1x1536"),
+    pytest.param("qwen3-0.6b", 5, 1536, (5, 1536), id="verify_5x1536"),
+    pytest.param("qwen3-0.6b", 256, 1536, (256, 1536), id="extend_256x1536"),
+    pytest.param("qwen3-0.6b", 512, 1024, (512, 1024), id="prefill_512x1024"),
+    pytest.param("qwen3-0.6b", 1024, 1024, (512, 1024),
+                 id="prefill_1024x1024"),
+    pytest.param("roberta-large", 128, 128, (128, 128), id="train_128x128"),
+    pytest.param("deepseek-v2-lite", 1, 1536, (1, 1536), id="mla_decode"),
+    # past the budget the keys go in kv_chunk pieces, as before
+    pytest.param("qwen3-0.6b", 1, 600_000, (1, 1024), id="decode_past"),
+    pytest.param("qwen3-0.6b", 512, 1536, (512, 1024), id="extend_past"),
+    pytest.param("qwen3-0.6b", 1024, 4096, (512, 1024), id="prefill_past"),
+])
+def test_flash_kv_tile_choice(arch, Sq, Skv, want):
+    """The flash tile under each configuration's q_chunk x kv_chunk
+    budget: one kv chunk when a q tile's scores over every key fit it;
+    prefill and train keep min(q_chunk, Sq) x min(kv_chunk, Skv)."""
+    from repro.configs import get
+    from repro.models.flash import _kv_tile
+
+    cfg = get(arch)
+    assert _kv_tile(Sq, Skv, cfg.q_chunk, cfg.kv_chunk) == want
+    if Sq >= cfg.q_chunk or Skv <= cfg.kv_chunk:  # prefill and train
+        assert want == (min(cfg.q_chunk, Sq), min(cfg.kv_chunk, Skv))

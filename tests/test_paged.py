@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from conftest import stacked_groups, tiny_cfg
+from conftest import record_kv_tiles, stacked_groups, tiny_cfg
 from repro.common.types import Group, Slot
 from repro.kernels import ops, ref
 from repro.models import model as M
@@ -221,6 +221,45 @@ def test_paged_decode_logits_bit_exact(group):
                                            jnp.asarray(pos), tables)
         np.testing.assert_array_equal(np.asarray(lg_c), np.asarray(lg_p))
         tok = np.asarray(jnp.argmax(lg_c[:, -1:], axis=-1), np.int32)
+
+
+def test_paged_decode_view_past_kv_chunk_is_one_chunk(monkeypatch):
+    """A paged decode view longer than kv_chunk and not a multiple of it
+    (5 pages x 8 = 40 positions, kv_chunk 16) is one kv chunk: the
+    compiled step pads neither K nor V, and its logits are the contiguous
+    decode's, and within fp32 rounding those of the whole sequence run
+    through several kv chunks at once."""
+    tiles = record_kv_tiles(monkeypatch)
+    cfg, params = _world(q_chunk=8, kv_chunk=16)
+    max_len, page = 40, 8
+    eng = ServeEngine(cfg, params)
+    toks = np.asarray(jax.random.randint(KEY, (1, 17), 1, 96), np.int32)
+    # both caches from one 16-token bucket, so the prefills match too
+    prompt = np.pad(toks[:, :11], ((0, 0), (0, 5)))
+    _, caches = eng.prefill(prompt, max_len, last_pos=10)
+    pool = eng.init_paged_pool(num_blocks=8, page=page)
+    tables = np.asarray([[2, 1, 3, 5, 4]], np.int32)
+    _, fresh = eng.prefill(prompt, 16, last_pos=10)
+    pool = eng.paged_insert(pool, fresh, tables[0, :2])
+
+    text = eng._decode_paged.lower(
+        eng.params, pool, jnp.asarray(toks[:, 11:12]),
+        jnp.asarray([11], np.int32), tables).as_text()
+    assert "stablehlo.pad" not in text
+    assert {t for t in tiles if t[0][0] == 1} == {((1, 40), (1, 40))}, tiles
+
+    paged = []
+    for t in range(11, 17):
+        tok, pos = jnp.asarray(toks[:, t:t + 1]), jnp.asarray([t], np.int32)
+        lg_c, caches = eng.decode_step(caches, tok, pos)
+        lg_p, pool = eng.paged_decode_step(pool, tok, pos, tables)
+        np.testing.assert_array_equal(np.asarray(lg_c), np.asarray(lg_p))
+        paged.append(np.asarray(lg_p[:, 0]))
+    tiles.clear()
+    full, _ = M.forward_lm(params, cfg, jnp.asarray(toks))
+    assert ((17, 17), (8, 16)) in tiles  # 8 x 17 is past the 8 x 16 budget
+    np.testing.assert_allclose(np.stack(paged, 1), np.asarray(full[:, 11:]),
+                               atol=2e-4)
 
 
 @pytest.mark.parametrize("step,kv_quant", [
